@@ -23,6 +23,7 @@ import sys
 import time
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (
     bench_build_time,
@@ -92,6 +93,7 @@ def main() -> None:
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
     profile = "full" if args.full else ("smoke" if args.smoke else "quick")
+    enable_compile_cache()
     only = None
     if args.only:
         only = {m.strip() for m in args.only.split(",") if m.strip()}

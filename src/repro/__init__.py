@@ -1,9 +1,2 @@
 """repro: reproduction of "On Optimally Partitioning Variable-Byte Codes"
-grown into a jax/pallas serving system.
-
-Importing any ``repro.*`` module first runs this package init, which installs
-the jax version-compat backfills (see ``repro.compat``) so the rest of the
-codebase can target one jax API surface.
-"""
-
-from . import compat  # noqa: F401  (side effect: jax API backfills)
+grown into a jax/pallas serving system."""

@@ -7,9 +7,9 @@ the two could silently disagree.  Both now resolve through here.
 
 Keep this module importable WITHOUT the repro package: measure_cov loads
 this FILE directly via importlib (spec_from_file_location) so that tracing
-can start before anything imports ``repro`` (importing the package pulls
-``repro.compat`` and therefore jax, whose module-level lines would then
-execute untraced and depress the measured coverage).  Stdlib imports only.
+can start before anything imports ``repro`` (the repro modules pull in
+jax, whose module-level lines would then execute untraced and depress the
+measured coverage).  Stdlib imports only.
 """
 
 from __future__ import annotations

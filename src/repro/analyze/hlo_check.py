@@ -116,12 +116,13 @@ def check_hlo_text(
     return findings
 
 
-def graph_specs(backend: str = "ref"):
+def graph_specs(backend: str = "ref", nr: int | None = None, nb: int = 64):
     """name -> (traceable fn, example args) for the registered graph halves.
 
     Arguments are synthetic but shaped exactly as the engines stage them:
-    one ``BM``-row pow2 bucket of gathered arena rows (values are
-    irrelevant -- only the traced graph matters).
+    one pow2 bucket of ``nr`` gathered arena rows (default ``BM``) over an
+    ``nb``-block arena (values are irrelevant -- only the traced graph
+    matters).  Pallas kernels are traced compiled (``interpret=False``).
     """
     import jax.numpy as jnp
 
@@ -136,7 +137,8 @@ def graph_specs(backend: str = "ref"):
     from repro.kernels.ef_search.kernel import EF_HI_WORDS
     from repro.kernels.vbyte_decode.kernel import BLOCK_BYTES, BLOCK_VALS, BM
 
-    nr, nb, stride = BM, 64, 131
+    nr = BM if nr is None else nr
+    stride = 131
     rng = np.random.default_rng(0)
     lens = jnp.asarray(np.ones((nr, BLOCK_VALS), np.int32))
     data = jnp.asarray(rng.integers(0, 255, (nr, BLOCK_BYTES)).astype(np.uint8))
@@ -146,8 +148,8 @@ def graph_specs(backend: str = "ref"):
     idf = jnp.asarray(np.ones(nr, np.float32))
     table = jnp.asarray(np.linspace(0.5, 2.0, 256).astype(np.float32))
     k1p1 = jnp.float32(2.2)
-    keys = jnp.asarray(np.arange(nb, dtype=np.int64) * 7)
-    offs = jnp.asarray(np.array([0, nb], np.int64))
+    last = jnp.asarray(np.arange(nb, dtype=np.int32) * 7)
+    offs = jnp.asarray(np.array([0, nb], np.int32))
     terms = jnp.asarray(np.zeros(nr, np.int32))
     probes = jnp.asarray(np.zeros(nr, np.int32))
     qb = jnp.asarray(np.zeros((nr, BLOCK_VALS), np.int32))
@@ -158,7 +160,7 @@ def graph_specs(backend: str = "ref"):
     ef_lb = jnp.asarray(np.zeros(nr, np.int32))
 
     def locate(t, p):
-        return locate_graph(keys, offs, stride, nb, t, p)
+        return locate_graph(last, offs, stride, nb.bit_length(), t, p)
 
     def decode_search(ln, d, b, p):
         return decode_search_graph(ln, d, b, p, backend, False)

@@ -25,9 +25,12 @@ Per-block sidecars make every block self-decoding and directly searchable:
     pushed down to block granularity);
   * ``lane_valid[b, i]`` -- mask of real (non-padding) lanes.
 
-``dev`` uploads the arrays to the default jax device once, int32-narrowed;
-``device_ok`` says whether the int32 key space is wide enough (it is unless
-``n_lists * stride`` overflows 31 bits -- then the numpy path serves).
+``dev`` uploads the arrays to the default jax device once, narrowed to
+int32 by ``to_i32``, which raises instead of wrapping.  ``block_keys``
+outgrow 31 bits on any real index (``n_lists * stride``), so they stay on
+the host: the device locate (``engine_core.locate_graph``) searches each
+list's own block range of ``block_last`` -- the last real docID per block,
+< 2^31 because ``build_arena`` refuses docIDs above ``MAX_DOCID``.
 
 MULTI-CODEC arenas (DESIGN.md §14): under ``codec_policy="auto"`` blocks of
 Elias-Fano-tagged partitions (and under ``"ef"`` every eligible block) are
@@ -69,6 +72,21 @@ TAG_EF = 2  # mirrors repro.core.index (which imports this module)
 CODEC_SVB = 0  # block_codec values
 CODEC_EF = 1
 CODEC_POLICIES = ("svb", "auto", "ef")
+
+# largest docID the device layout holds: padding lanes run up to 128 past a
+# block's last real docID, and ``stride`` = max docID + 2, all in int32
+MAX_DOCID = 2**31 - BLOCK_VALS - 2
+
+
+def to_i32(a, name: str) -> np.ndarray:
+    """``a`` narrowed to int32 for the device; raises rather than wraps."""
+    a = np.asarray(a)
+    info = np.iinfo(np.int32)
+    if a.size and (a.min() < info.min or a.max() > info.max):
+        raise OverflowError(
+            f"{name} spans [{a.min()}, {a.max()}], outside int32"
+        )
+    return a.astype(np.int32)
 
 
 @dataclass
@@ -136,7 +154,6 @@ class DeviceArena:
     list_blk_offsets: np.ndarray  # [n_lists + 1] int64
     stride: int = 0
     n_blocks: int = 0
-    device_ok: bool = True
     ranked: RankedSidecar | None = None
     # multi-codec layout (None on single-codec arenas: lens/data rows are
     # then block rows, the PR 1 identity layout)
@@ -152,36 +169,41 @@ class DeviceArena:
         """True when blocks mix codecs (lens/data hold SVB rows only)."""
         return self.block_codec is not None
 
+    def block_last(self) -> np.ndarray:
+        """[n_blocks] int64 last real docID of each block."""
+        return self.block_keys - self.part_list[self.part_of_block] * self.stride
+
+    @property
+    def locate_iters(self) -> int:
+        """Binary-search steps that cover the longest list's block range."""
+        counts = np.diff(self.list_blk_offsets)
+        return int(counts.max()).bit_length() if counts.size else 0
+
     @property
     def dev(self):
-        """jnp copies of the arena, uploaded once (int32-narrowed keys)."""
+        """jnp copies of the arena, uploaded once (checked int32 narrowing)."""
         if self._dev is None:
             import jax.numpy as jnp
             from types import SimpleNamespace
 
+            def up(name, arr):
+                return jnp.asarray(to_i32(arr, name))
+
             self._dev = SimpleNamespace(
                 lens=jnp.asarray(self.lens),
                 data=jnp.asarray(self.data),
-                block_base=jnp.asarray(self.block_base.astype(np.int32)),
-                block_keys=jnp.asarray(self.block_keys.astype(np.int32)),
-                part_of_block=jnp.asarray(self.part_of_block.astype(np.int32)),
-                first_blk=jnp.asarray(self.first_blk.astype(np.int32)),
-                list_blk_offsets=jnp.asarray(
-                    self.list_blk_offsets.astype(np.int32)
-                ),
+                block_base=up("block_base", self.block_base),
+                block_last=up("block_last", self.block_last()),
+                part_of_block=up("part_of_block", self.part_of_block),
+                first_blk=up("first_blk", self.first_blk),
+                list_blk_offsets=up("list_blk_offsets", self.list_blk_offsets),
             )
             if self.block_codec is not None:
-                self._dev.block_codec = jnp.asarray(
-                    self.block_codec.astype(np.int32)
-                )
-                self._dev.codec_row = jnp.asarray(
-                    self.codec_row.astype(np.int32)
-                )
-                self._dev.ef_lo = jnp.asarray(self.ef_lo.astype(np.int32))
-                self._dev.ef_hi = jnp.asarray(self.ef_hi.astype(np.int32))
-                self._dev.ef_lbits = jnp.asarray(
-                    self.ef_lbits.astype(np.int32)
-                )
+                self._dev.block_codec = up("block_codec", self.block_codec)
+                self._dev.codec_row = up("codec_row", self.codec_row)
+                self._dev.ef_lo = up("ef_lo", self.ef_lo)
+                self._dev.ef_hi = up("ef_hi", self.ef_hi)
+                self._dev.ef_lbits = up("ef_lbits", self.ef_lbits)
         return self._dev
 
     def nbytes(self) -> int:
@@ -221,6 +243,11 @@ def build_arena(index, codec_policy: str = "auto") -> DeviceArena:
         )
 
     n_parts = len(index.endpoints)
+    if n_parts and int(index.endpoints.max()) > MAX_DOCID:
+        raise ValueError(
+            f"docID {int(index.endpoints.max())} exceeds {MAX_DOCID}: the "
+            "device arena holds docIDs in int32 lanes"
+        )
     sizes = index.sizes.astype(np.int64)
     part_counts = np.diff(index.list_part_offsets)
     part_list = np.repeat(np.arange(index.n_lists, dtype=np.int64), part_counts)
@@ -323,8 +350,6 @@ def build_arena(index, codec_policy: str = "auto") -> DeviceArena:
         list_blk_offsets[:] = np.concatenate(
             [first_blk, [nb]]
         )[index.list_part_offsets]
-    # int32 device keys must hold probe + term*stride and value + 128
-    device_ok = (index.n_lists + 1) * stride < 2**31 - BLOCK_VALS - 2
 
     ranked = None
     if ranked_on:
@@ -348,7 +373,6 @@ def build_arena(index, codec_policy: str = "auto") -> DeviceArena:
         list_blk_offsets=list_blk_offsets,
         stride=stride,
         n_blocks=nb,
-        device_ok=bool(device_ok),
         ranked=ranked,
         block_codec=block_codec,
         codec_row=codec_row,

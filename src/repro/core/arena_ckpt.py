@@ -39,7 +39,6 @@ UNRANKED_KEYS = (
     "block_base",
     "block_keys",
     "data",
-    "device_ok",
     "first_blk",
     "lane_valid",
     "lens",
@@ -99,7 +98,6 @@ def arena_to_tree(a: DeviceArena) -> dict:
         "list_blk_offsets": a.list_blk_offsets,
         "stride": np.int64(a.stride),
         "n_blocks": np.int64(a.n_blocks),
-        "device_ok": np.bool_(a.device_ok),
     }
     if a.ranked is not None:
         r = a.ranked
@@ -172,7 +170,6 @@ def tree_to_arena(tree: dict) -> DeviceArena:
         list_blk_offsets=np.asarray(tree["list_blk_offsets"]),
         stride=int(tree["stride"]),
         n_blocks=int(tree["n_blocks"]),
-        device_ok=bool(tree["device_ok"]),
         ranked=ranked,
         block_codec=(
             np.asarray(tree["block_codec"]) if "block_codec" in tree else None

@@ -1,13 +1,13 @@
 """Shared flat-mirror / locate machinery of the batched engines (one place).
 
 ``QueryEngine`` (boolean AND / NextGEQ) and ``TopKEngine`` (BM25 top-k) both
-serve batches the same way: locate each (term, probe) cursor's arena row with
-ONE searchsorted over globally monotone keys, then resolve the cursor inside
-the located row.  Until PR 4 the machinery behind that -- the flat host
+serve batches the same way: locate each (term, probe) cursor's arena row --
+on the host with ONE searchsorted over globally monotone int64 keys, on the
+device with a binary search of the cursor's own list -- then resolve the
+cursor inside the located row.  The machinery behind that -- the flat host
 mirror, the lane-key construction with its padding clamp, the pow2 cursor
-bucketing, and the int32 probe clip -- lived TWICE, once per engine, and the
-ROADMAP flagged the duplication as a correctness hazard: the subtleties are
-exactly the kind that drift apart silently.  They now live here, once.
+bucketing, and the int32 probe clip -- lives here, once: its subtleties
+are exactly the kind that drift apart silently between two copies.
 
 The subtleties, for the record:
 
@@ -91,36 +91,64 @@ def group_cursors(terms, probes, stride: int):
     return idx, inv
 
 
-def locate_graph(block_keys, list_blk_offsets, stride, nb, terms, probes):
-    """Jitted-graph locate over resident keys: ONE searchsorted.
+def locate_graph(block_last, list_blk_offsets, stride, iters, terms, probes):
+    """Jitted-graph locate over resident per-block last docIDs.
 
     Traces int32 cursor arrays into ``(rows, pe, past)``: ``rows`` the
     arena row holding each cursor's answer (clamped in-range), ``pe`` the
     effective probe (0 where past the end), ``past`` the past-the-end
-    mask.  Every device pipeline -- both engines' jitted fns AND the
-    shard_map bodies of ``core.shard`` -- opens with exactly this graph;
-    it exists ONCE, here.
+    mask.  Each cursor binary-searches ITS list's block range
+    ``[list_blk_offsets[t], list_blk_offsets[t + 1])`` of ``block_last``
+    for the first block whose last real docID is >= the probe: the block
+    the host's one searchsorted over ``block_keys`` finds, with no key
+    that grows with the list count (``n_lists * stride`` passes 2^31 on a
+    real index).  ``iters`` (static) is the bit length of the longest
+    list's block count.  Every device pipeline -- both engines' jitted
+    fns AND the shard_map bodies of ``core.shard`` -- opens with exactly
+    this graph; it exists ONCE, here.
     """
     import jax.numpy as jnp
 
+    nb = block_last.shape[0]
     pc = jnp.clip(probes, 0, stride - 1)
-    k = jnp.searchsorted(block_keys, pc + terms * stride, side="left").astype(
-        jnp.int32
-    )
-    past = k >= list_blk_offsets[terms + 1]
-    rows = jnp.minimum(k, nb - 1)
+    lo = list_blk_offsets[terms]
+    end = list_blk_offsets[terms + 1]
+    hi = end
+    for _ in range(iters):
+        mid = (lo + hi) >> 1
+        right = (lo < hi) & (block_last[jnp.minimum(mid, nb - 1)] < pc)
+        lo = jnp.where(right, mid + 1, lo)
+        hi = jnp.where(right, hi, mid)
+    past = lo >= end
+    rows = jnp.minimum(lo, nb - 1)
     pe = jnp.where(past, 0, pc)
     return rows, pe, past
 
 
-def build_locate_dev(arena):
-    """``locate_graph`` closed over one arena's resident device arrays."""
-    dev = arena.dev
-    stride, nb = arena.stride, arena.n_blocks
+def jit_resident(fn, resident: dict, **jit_kw):
+    """``jax.jit(fn)``, called as ``fn(resident, *args)``.
 
-    def locate(terms, probes):
+    The resident device arrays travel as an argument on every call.  Closed
+    over instead, jit would bake them into each compiled program as
+    constants: one copy of the arena per cursor bucket, in device and host
+    memory alike.
+    """
+    import functools
+
+    import jax
+
+    return functools.partial(jax.jit(fn, **jit_kw), resident)
+
+
+def build_locate_dev(arena):
+    """``locate_graph`` over one arena, as ``locate(dev, terms, probes)``
+    with ``dev`` the arena's resident arrays (``vars(arena.dev)``)."""
+    stride, iters = arena.stride, arena.locate_iters
+
+    def locate(dev, terms, probes):
         return locate_graph(
-            dev.block_keys, dev.list_blk_offsets, stride, nb, terms, probes
+            dev["block_last"], dev["list_blk_offsets"], stride, iters,
+            terms, probes,
         )
 
     return locate
@@ -657,33 +685,31 @@ class EngineCore:
     # fused locate -> decode_search, jitted device path
     # ------------------------------------------------------------------
     def _build_jax_fn(self):
-        import jax
         import jax.numpy as jnp
 
-        dev = self.arena.dev
         multi = self.arena.block_codec is not None
         locate = build_locate_dev(self.arena)
         backend, interpret = self.backend, self.interpret
 
-        def fn(terms, probes):
-            rows, pe, past = locate(terms, probes)
+        def fn(dev, terms, probes):
+            rows, pe, past = locate(dev, terms, probes)
             # multi-codec arenas store SVB tiles compacted: the gather goes
             # through codec_row (EF blocks alias row 0, but every cursor
             # reaching this fn was bucketed onto an SVB block by the host)
-            sr = dev.codec_row[rows] if multi else rows
+            sr = dev["codec_row"][rows] if multi else rows
             value, rank_in = decode_search_graph(
-                dev.lens[sr],
-                dev.data[sr],
-                dev.block_base[rows],
+                dev["lens"][sr],
+                dev["data"][sr],
+                dev["block_base"][rows],
                 pe,
                 backend,
                 interpret,
             )
-            part = dev.part_of_block[rows]
-            rank = (rows - dev.first_blk[part]) * BLOCK_VALS + rank_in
+            part = dev["part_of_block"][rows]
+            rank = (rows - dev["first_blk"][part]) * BLOCK_VALS + rank_in
             return jnp.where(past, -1, value), jnp.where(past, -1, rank)
 
-        return jax.jit(fn)
+        return jit_resident(fn, vars(self.arena.dev))
 
     def _build_ef_jax_fn(self):
         """Jitted locate -> EF-NextGEQ pipeline (multi-codec arenas, §14).
@@ -692,30 +718,28 @@ class EngineCore:
         arithmetic, ``ef_search_graph`` in place of ``decode_search_graph``
         with the tile gather routed through ``codec_row``.
         """
-        import jax
         import jax.numpy as jnp
 
-        dev = self.arena.dev
         locate = build_locate_dev(self.arena)
         backend, interpret = self.backend, self.interpret
 
-        def fn(terms, probes):
-            rows, pe, past = locate(terms, probes)
-            er = dev.codec_row[rows]
+        def fn(dev, terms, probes):
+            rows, pe, past = locate(dev, terms, probes)
+            er = dev["codec_row"][rows]
             value, rank_in = ef_search_graph(
-                dev.ef_lo[er],
-                dev.ef_hi[er],
-                dev.ef_lbits[er],
-                dev.block_base[rows],
+                dev["ef_lo"][er],
+                dev["ef_hi"][er],
+                dev["ef_lbits"][er],
+                dev["block_base"][rows],
                 pe,
                 backend,
                 interpret,
             )
-            part = dev.part_of_block[rows]
-            rank = (rows - dev.first_blk[part]) * BLOCK_VALS + rank_in
+            part = dev["part_of_block"][rows]
+            rank = (rows - dev["first_blk"][part]) * BLOCK_VALS + rank_in
             return jnp.where(past, -1, value), jnp.where(past, -1, rank)
 
-        return jax.jit(fn)
+        return jit_resident(fn, vars(self.arena.dev))
 
     def _dispatch_jax(self, fn, terms, probes):
         """Stage one cursor bucket and run one jitted pipeline over it."""
@@ -778,7 +802,7 @@ class EngineCore:
 
     @property
     def use_device(self) -> bool:
-        return self.backend in ("ref", "pallas") and self.arena.device_ok
+        return self.backend in ("ref", "pallas")
 
     def fused_search(
         self, terms, probes, with_rank: bool = True, trusted: bool = False

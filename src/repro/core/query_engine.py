@@ -302,10 +302,6 @@ class QueryEngine:
     # ------------------------------------------------------------------
     @property
     def _use_device(self) -> bool:
-        if self.sharded is not None:
-            # all_device_ok is computed from the routing metadata alone --
-            # it must not force the per-shard arena slices to materialize
-            return self.backend in ("ref", "pallas") and self.sharded.all_device_ok
         return self.core.use_device
 
     def _shard_core(self, s: int) -> EngineCore:

@@ -57,7 +57,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro import obs
-from repro.core.arena import DeviceArena, RankedSidecar
+from repro.core.arena import DeviceArena, RankedSidecar, to_i32
 from repro.kernels.blockmax_pivot.kernel import QMIN_NONE
 from repro.kernels.vbyte_decode.kernel import BLOCK_BYTES, BLOCK_VALS
 
@@ -277,12 +277,6 @@ class ShardedArena:
             self._pchunks = [build_pivot_chunks(sub) for sub in self.shards]
         return self._pchunks
 
-    @property
-    def all_device_ok(self) -> bool:
-        """Per-shard int32-key feasibility, WITHOUT materializing slices."""
-        nl_m = max((len(f) for f in self.lists_of), default=0)
-        return bool((nl_m + 1) * self.arena.stride < 2**31 - BLOCK_VALS - 2)
-
     def shard_nbytes(self) -> list[int]:
         return [sub.nbytes() for sub in self.shards]
 
@@ -293,10 +287,10 @@ class ShardedArena:
         """Host-side [S, ...] stacking, padded to the largest shard.
 
         Padding rows are benign by construction: lens=1/data=0 decodes to
-        zeros, ``block_keys`` pads with int32 max (no probe key can reach
-        it -- ``device_ok`` guarantees probe keys fit 31 bits), and
-        ``list_blk_offsets`` pads by repeating its last value so any
-        staged-padding cursor resolves past-the-end.
+        zeros, ``block_last`` pads with int32 max (no list's block range
+        covers a padding row), and ``list_blk_offsets`` pads by repeating
+        its last value so any staged-padding cursor resolves past-the-end.
+        Every narrowing is checked (``to_i32``).
 
         NOT cached: the only consumer is ``stacked_dev`` (which caches the
         DEVICE copies); keeping the padded host stacking alive would pin a
@@ -315,7 +309,7 @@ class ShardedArena:
             "lens": np.ones((S, nb_m, BLOCK_VALS), np.int32),
             "data": np.zeros((S, nb_m, BLOCK_BYTES), np.uint8),
             "block_base": np.zeros((S, nb_m), np.int32),
-            "block_keys": np.full((S, nb_m), INT32_MAX, np.int32),
+            "block_last": np.full((S, nb_m), INT32_MAX, np.int32),
             "part_of_block": np.zeros((S, nb_m), np.int32),
             "first_blk": np.zeros((S, np_m), np.int32),
             "list_blk_offsets": np.zeros((S, nl_m + 1), np.int32),
@@ -331,11 +325,11 @@ class ShardedArena:
             nb, nl = sub.n_blocks, len(self.lists_of[s])
             st["lens"][s, :nb] = sub.lens[:nb]
             st["data"][s, :nb] = sub.data[:nb]
-            st["block_base"][s, :nb] = sub.block_base.astype(np.int32)
-            st["block_keys"][s, :nb] = sub.block_keys.astype(np.int32)
-            st["part_of_block"][s, :nb] = sub.part_of_block.astype(np.int32)
-            st["first_blk"][s, : len(sub.first_blk)] = sub.first_blk.astype(np.int32)
-            lbo = sub.list_blk_offsets.astype(np.int32)
+            st["block_base"][s, :nb] = to_i32(sub.block_base, "block_base")
+            st["block_last"][s, :nb] = to_i32(sub.block_last(), "block_last")
+            st["part_of_block"][s, :nb] = to_i32(sub.part_of_block, "part_of_block")
+            st["first_blk"][s, : len(sub.first_blk)] = to_i32(sub.first_blk, "first_blk")
+            lbo = to_i32(sub.list_blk_offsets, "list_blk_offsets")
             st["list_blk_offsets"][s, : nl + 1] = lbo
             st["list_blk_offsets"][s, nl + 1 :] = np.int32(nb)
             if ranked:
@@ -344,7 +338,7 @@ class ShardedArena:
                 st["freq_data"][s, :nb] = r.freq_data[:nb]
                 st["norm_q"][s, :nb] = r.norm_q
                 st["idf"][s, :nl] = r.idf
-                st["lob"][s, :nb] = sub.part_list[sub.part_of_block].astype(np.int32)
+                st["lob"][s, :nb] = to_i32(sub.part_list[sub.part_of_block], "lob")
         return st
 
     def stacked_dev(self) -> dict:
@@ -427,7 +421,7 @@ def _slice_arena(
         first_blk_s[1:] = np.cumsum(n_blk_s)[:-1]
     part_list_s = local_list[a.part_list[parts_s]]
     part_of_block_s = np.repeat(np.arange(len(parts_s), dtype=np.int64), n_blk_s)
-    block_last = a.block_keys[rows_s] - list_of_block[rows_s] * a.stride
+    block_last = a.block_last()[rows_s]
     blk_counts = a.list_blk_offsets[lists_s + 1] - a.list_blk_offsets[lists_s]
     list_blk_offsets_s = np.zeros(len(lists_s) + 1, np.int64)
     np.cumsum(blk_counts, out=list_blk_offsets_s[1:])
@@ -478,7 +472,6 @@ def _slice_arena(
         list_blk_offsets=list_blk_offsets_s,
         stride=a.stride,
         n_blocks=len(rows_s),
-        device_ok=bool((len(lists_s) + 1) * a.stride < 2**31 - BLOCK_VALS - 2),
         ranked=ranked,
         block_codec=block_codec_s,
         codec_row=codec_row_s,
@@ -521,6 +514,9 @@ class _ShardMapDispatch:
         # the mesh-path mirror of the per-shard EngineCore check
         self.injector = injector
         self.stride = sharded.arena.stride
+        # every shard holds whole lists, so the global arena's longest list
+        # bounds each shard's locate search
+        self.iters = sharded.arena.locate_iters
         # per-shard staging cap PER DISPATCH: batches whose fullest shard
         # exceeds it run in rounds, so gathered tiles stay bounded and jit
         # traces are reused (same role as TopKEngine.MAX_BUCKET unsharded)
@@ -654,10 +650,10 @@ class ShardMapSearch(_ShardMapDispatch):
         from repro.core.engine_core import decode_search_graph, locate_graph
 
         rows, pe, past = locate_graph(
-            arrs["block_keys"],
+            arrs["block_last"],
             arrs["list_blk_offsets"],
             self.stride,
-            arrs["block_keys"].shape[0],
+            self.iters,
             terms,
             probes,
         )
@@ -709,10 +705,10 @@ class ShardMapBM25(_ShardMapDispatch):
         from repro.kernels.bm25_score.ops import score_probe_graph
 
         rows, pe, past = locate_graph(
-            arrs["block_keys"],
+            arrs["block_last"],
             arrs["list_blk_offsets"],
             self.stride,
-            arrs["block_keys"].shape[0],
+            self.iters,
             terms,
             probes,
         )

@@ -18,14 +18,15 @@ to 128 consecutive blocks:
 
   * keeps the lanes (blocks) with ``block_max_q >= qmin[lane]``,
   * COMPACTS the kept lane indices to the front of the row (the candidate
-    block list), via the same one-hot MXU matmul trick as the decoders --
-    a cumsum of the keep mask gives each kept lane its target slot, and
-    ``lane @ [pos == slot]`` scatters with no per-lane control flow,
+    block list) with the same one-hot MXU matmul trick as the decoders: an
+    inclusive scan of the keep mask counts the kept lanes up to each lane,
+    and slot s receives the number of lanes whose count is <= s -- the
+    lane of the (s+1)-th kept block -- with no per-lane control flow,
   * emits the WAND pivot lane (lowest lane attaining the max surviving
     bound) and that max bound code.
 
-Everything is int32 arithmetic plus one f32 matmul over values <= 127
-(exact in f32), so all three backends (this kernel, the jnp ref, the numpy
+Everything is int32 arithmetic plus 0/1 matmuls whose sums are <= 128
+(exact in bf16 operands with f32 accumulation), so all three backends (this kernel, the jnp ref, the numpy
 mirror) are bit-identical by construction -- no FMA/rounding hazards.
 
 Layout mirrors ``bm25_score``: the qmin codes ride a full [nr, 128] int32
@@ -44,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.vbyte_decode.kernel import BLOCK_VALS, BM
+from repro.kernels.vbyte_decode.kernel import BLOCK_VALS, BM, lane_cumsum
 
 # int32 meta lanes (per gathered chunk row)
 PMETA_NBLK = 0  # number of valid lanes (blocks) in the chunk
@@ -71,17 +72,21 @@ def _pivot_tile(qb, qmin, nblk):
     keep = (qb >= qmin) & (lane < nblk)
     keep_i = keep.astype(jnp.int32)
     count = jnp.sum(keep_i, axis=1, keepdims=True)
-    pos = jnp.cumsum(keep_i, axis=1) - 1
-    # one-hot MXU scatter: kept lane l lands in slot pos[l]; lane ids are
-    # <= 127 so the f32 contraction (one nonzero product per slot) is exact
-    slot = jax.lax.broadcasted_iota(jnp.int32, (BM, BLOCK_VALS, BLOCK_VALS), 2)
-    sel = ((pos[:, :, None] == slot) & keep[:, :, None]).astype(jnp.float32)
-    compact = jax.lax.dot_general(
-        lane.astype(jnp.float32),
-        sel,
-        (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ).astype(jnp.int32)
+    kept_upto = lane_cumsum(keep_i)  # kept lanes in [0, l]
+    # one-hot MXU compaction, one row at a time: ones @ [slot, lane] 0/1
+    # with the lane axis contracted counts, per slot s, the lanes whose
+    # kept count is <= s; sums <= 128, exact in bf16 with f32 accumulation
+    slot = jax.lax.broadcasted_iota(jnp.int32, (BLOCK_VALS, BLOCK_VALS), 0)
+    ones = jnp.ones((BM, BLOCK_VALS), jnp.bfloat16)
+    row = jax.lax.broadcasted_iota(jnp.int32, (BM, BLOCK_VALS), 0)
+    compact = jnp.zeros((BM, BLOCK_VALS), jnp.int32)
+    for r in range(BM):
+        below = (kept_upto[r : r + 1, :] <= slot).astype(jnp.bfloat16)
+        got = jax.lax.dot_general(
+            ones, below, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
+        compact = jnp.where(row == r, got, compact)
     compact = jnp.where(lane < count, compact, -1)
     maxq = jnp.max(jnp.where(keep, qb, -1), axis=1, keepdims=True)
     pivot = jnp.min(

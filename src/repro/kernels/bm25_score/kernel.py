@@ -10,12 +10,17 @@ of ``repro.ranked.bm25`` on the VPU:
 
     score = idf * (tf * (k1 + 1)) / (tf + K_hat)
 
-The norm dequantization MUST be a GATHER from the 256-entry f32 table of
-``repro.ranked.bm25.norm_table`` -- expressed as a second one-hot matmul
-(``table[BM, 256] @ [code == c]``) so it runs on the MXU with no per-lane
+with the quotient taken by ``div_rn``, exact where the chip's divide is
+not.  The norm dequantization MUST be a GATHER from the 256-entry f32 table of
+``repro.ranked.bm25.norm_table`` -- expressed as one-hot matmuls (``table
+@ [code == c]``, one per tile row) so it runs on the MXU with no per-lane
 control flow, and so the kernel reproduces the numpy contract BIT-EXACTLY.
-Do NOT "simplify" it into the arithmetic ``kmin + kstep * q`` form: in-graph
-that mul+add gets FMA-contracted by XLA and drifts 1 ulp off the oracle,
+The table is split into three bf16 parts (hi + mid + lo == table exactly),
+each gathered by its own matmul and summed back in that order: every
+product is exact and every output sums one nonzero product, so the gather
+is exact whatever precision the MXU gives f32 operands.  Do NOT
+"simplify" it into the arithmetic ``kmin + kstep * q`` form: in-graph that
+mul+add gets FMA-contracted by XLA and drifts 1 ulp off the oracle,
 breaking the cross-backend bit-identity the top-k engine relies on.
 
 Two kernels:
@@ -46,6 +51,7 @@ from repro.kernels.vbyte_decode.kernel import (
     META_BASE,
     META_PROBE,
     _decode_tile,
+    lane_cumsum,
 )
 
 # float32 meta lanes (per gathered row)
@@ -55,30 +61,70 @@ FMETA_K1P1 = 1   # k1 + 1
 NORM_LEVELS = 256
 
 
-def _score_tile(flens, fdata_f32, norm_i32, table_f32, fmeta):
+def div_rn(num, den):
+    """``num / den`` for positive normal f32 with a normal quotient,
+    rounded to nearest even -- the IEEE quotient numpy computes.
+
+    The TPU's f32 divide is not correctly rounded (1 ulp off the numpy
+    contract on real scores), so the 24-bit mantissas are divided exactly
+    by restoring division in int32 -- 24 quotient bits plus a guard bit,
+    the remainder as sticky bit -- and rounded by hand.  Integer ops and
+    bitcasts only: identical inside a Pallas kernel and in plain XLA.
+    """
+    nb = jax.lax.bitcast_convert_type(num, jnp.int32)
+    db = jax.lax.bitcast_convert_type(den, jnp.int32)
+    mn = (nb & 0x7FFFFF) | 0x800000
+    md = (db & 0x7FFFFF) | 0x800000
+    exp = ((nb >> 23) & 0xFF) - ((db >> 23) & 0xFF) + 127
+    below = mn < md  # quotient mantissa < 1: take one more dividend bit
+    rem = jnp.where(below, mn << 1, mn)
+    exp = jnp.where(below, exp - 1, exp)
+    q = jnp.zeros_like(rem)
+    for _ in range(25):
+        bit = (rem >= md).astype(jnp.int32)
+        q = (q << 1) | bit
+        rem = (rem - bit * md) << 1
+    mant = q >> 1
+    mant = mant + ((q & 1) & (jnp.where(rem != 0, 1, 0) | (mant & 1)))
+    carry = mant >> 24  # rounding carried into a 25th bit
+    bits = ((exp + carry) << 23) | ((mant >> carry) & 0x7FFFFF)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _gather_table(table_f32, norm_i32):
+    """k_hat[r, i] = table[norm[r, i]]: [BM,256] f32 table (rows identical)
+    + [BM,128] i32 codes -> [BM,128] f32, bit-exact (module docstring)."""
+    hi = table_f32.astype(jnp.bfloat16)
+    r1 = table_f32 - hi.astype(jnp.float32)
+    mid = r1.astype(jnp.bfloat16)
+    lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    c_iota = jax.lax.broadcasted_iota(jnp.int32, (NORM_LEVELS, BLOCK_VALS), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (BM, BLOCK_VALS), 0)
+    k_hat = jnp.zeros((BM, BLOCK_VALS), jnp.float32)
+    for r in range(BM):
+        sel = (c_iota == norm_i32[r : r + 1, :]).astype(jnp.bfloat16)
+        parts = [
+            jnp.dot(t, sel, preferred_element_type=jnp.float32)
+            for t in (hi, mid, lo)
+        ]
+        k_hat = jnp.where(row == r, (parts[0] + parts[1]) + parts[2], k_hat)
+    return k_hat
+
+
+def _score_tile(flens, fdata, norm_i32, table_f32, fmeta):
     """[BM,128] freq tile + norm codes + [BM,256] table -> [BM,128] scores."""
-    tf = (_decode_tile(flens, fdata_f32) + 1).astype(jnp.float32)
+    tf = (_decode_tile(flens, fdata) + 1).astype(jnp.float32)
     k1p1 = fmeta[:, FMETA_K1P1 : FMETA_K1P1 + 1]
     idf_t = fmeta[:, FMETA_IDF : FMETA_IDF + 1]
-    # norm dequant as a one-hot MXU gather from the shared f32 table: the
-    # single nonzero product makes the contraction exact (bit-equal to the
-    # numpy table lookup), unlike an in-graph mul+add which XLA would FMA
-    c_iota = jax.lax.broadcasted_iota(
-        jnp.int32, (BM, NORM_LEVELS, BLOCK_VALS), 1
-    )
-    sel = (c_iota == norm_i32[:, None, :]).astype(jnp.float32)
-    k_hat = jax.lax.dot_general(
-        table_f32, sel, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    return idf_t * ((tf * k1p1) / (tf + k_hat))
+    k_hat = _gather_table(table_f32, norm_i32)
+    return idf_t * div_rn(tf * k1p1, tf + k_hat)
 
 
 def _score_kernel(flens_ref, fdata_ref, norm_ref, table_ref, fmeta_ref,
                   out_ref):
     out_ref[...] = _score_tile(
-        flens_ref[...], fdata_ref[...].astype(jnp.float32),
-        norm_ref[...], table_ref[...], fmeta_ref[...],
+        flens_ref[...], fdata_ref[...], norm_ref[...], table_ref[...],
+        fmeta_ref[...],
     )
 
 
@@ -118,13 +164,13 @@ def _score_probe_kernel(
     lens_ref, data_ref, flens_ref, fdata_ref, norm_ref, table_ref, meta_ref,
     fmeta_ref, out_ref,
 ):
-    gaps = _decode_tile(lens_ref[...], data_ref[...].astype(jnp.float32))
+    gaps = _decode_tile(lens_ref[...], data_ref[...])
     base = meta_ref[:, META_BASE : META_BASE + 1]
     probe = meta_ref[:, META_PROBE : META_PROBE + 1]
-    vals = base + jnp.cumsum(gaps + 1, axis=1)
+    vals = base + lane_cumsum(gaps + 1)
     scores = _score_tile(
-        flens_ref[...], fdata_ref[...].astype(jnp.float32),
-        norm_ref[...], table_ref[...], fmeta_ref[...],
+        flens_ref[...], fdata_ref[...], norm_ref[...], table_ref[...],
+        fmeta_ref[...],
     )
     # docIDs are strictly increasing within the row: at most one lane matches
     contrib = jnp.sum(

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.bm25_score.kernel import div_rn
 from repro.kernels.vbyte_decode.ref import decode_blocks_ref
 
 
@@ -14,11 +15,12 @@ def score_rows_ref(flens, fdata, norms, idf_rows, table, k1p1):
     [nr, 128] int32 codes; idf_rows: [nr] float32; table: [256] float32
     norm dequant table; k1p1: float32 scalar.  Returns [nr, 128] float32
     (padding lanes garbage).  The norm is GATHERED from the table, never
-    recomputed -- see ``repro.ranked.bm25.norm_table``.
+    recomputed -- see ``repro.ranked.bm25.norm_table`` -- and the quotient
+    is ``div_rn``'s correctly rounded one on every platform.
     """
     tf = (decode_blocks_ref(flens, fdata) + 1).astype(jnp.float32)
     k_hat = table[norms]
-    return idf_rows[:, None] * ((tf * k1p1) / (tf + k_hat))
+    return idf_rows[:, None] * div_rn(tf * k1p1, tf + k_hat)
 
 
 def score_probe_ref(

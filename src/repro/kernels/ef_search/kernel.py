@@ -8,7 +8,7 @@ uint16 low bits per lane plus a 384-bit unary high stream -- 128 one-bits
 staged META tile, so every in-kernel shift stays in non-negative int32.
 
 NextGEQ resolves with NO select-dictionary and NO per-lane control flow,
-just cumsums and reductions over the [BM, 384] bit tile (VPU-shaped):
+just lane-aligned counts over 16 bit planes of the high stream (VPU-shaped):
 
 * ``rank`` -- split the rebased probe into (hp, lp).  The position of the
   b-th zero is ``Z(b) = #{j : zcumsum_j <= b}``, so the count of lanes
@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.vbyte_decode.kernel import BLOCK_VALS, BM
+from repro.kernels.vbyte_decode.kernel import BLOCK_VALS, BM, lane_cumsum
 
 EF_HI_WORDS = 24  # 16 high-stream bits per staged int32 word
 EF_HI_BITS = EF_HI_WORDS * 16  # 384 = 128 one-bits + up to 256 zero-bits
@@ -46,42 +46,51 @@ EFMETA_PROBE = EF_HI_WORDS + 2
 _I32_MAX = 2**31 - 1  # python int: jnp constants would be captured by pallas
 
 
-def _ef_search_tile(lo, hi_words, lbits, base, probe):
-    """[BM,128] i32 lows + [BM,24] i32 high words + [BM,1] i32 scalars
-    -> [BM,1] (value, rank).  Shared by the kernel body below; the jnp
-    ref re-derives the same arithmetic over unstaged inputs."""
-    rows = lo.shape[0]
-    shift = jax.lax.broadcasted_iota(jnp.int32, (rows, EF_HI_WORDS, 16), 2)
-    # the inclusive one counts over the 384-bit stream, built
-    # hierarchically -- a length-16 scan within each word plus a length-24
-    # word-prefix scan -- instead of one length-384 scan, and kept in
-    # int8/int16 (the [BM,24,16] intermediates dominate memory traffic on
-    # big cursor waves; every count fits: inner <= 16, oc <= 128).  The
-    # zero counts are never materialized: zc_j = j+1 - oc_j, so
-    # ``zc_j <= b``  <=>  ``oc_j >= j+1-b``.
-    bits = ((hi_words[:, :, None] >> shift) & 1).astype(jnp.int8)
-    inner_oc = jnp.cumsum(bits, axis=2)  # within-word one counts
-    wo = inner_oc[:, :, 15:16].astype(jnp.int16)  # ones per word
-    oc = jnp.cumsum(wo, axis=1) - wo + inner_oc  # inclusive one counts
-    pos1 = (
-        jax.lax.broadcasted_iota(jnp.int16, (rows, EF_HI_WORDS, 16), 1) * 16
-        + shift.astype(jnp.int16) + 1
-    )  # j + 1 over the flat 384-bit stream
+def _ef_search_tile(lo, meta, lbits, base, probe):
+    """[BM,128] i32 lows + the [BM,128] i32 meta tile (high words in lanes
+    0..23) + [BM,1] i32 scalars -> [BM,1] (value, rank).
+
+    The 384-bit high stream is held as 16 bit planes, plane s holding bit s
+    of word w in lane w, so every count below is lane-aligned int32 VPU
+    work plus one lane scan.  The inclusive one count at stream position j
+    = 16w + s is ``before[w] + inner_s[w]``: the ones of the words before w
+    plus the ones of bits 0..s of word w.  The zero counts are never
+    materialized: zc_j = j+1 - oc_j, so ``zc_j <= b``  <=>  ``oc_j >=
+    j+1-b``.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, lo.shape, 1)
+    word = lane < EF_HI_WORDS
+    words = jnp.where(word, meta, 0)
+    inner = []  # inner[s] = ones among bits 0..s of each word
+    acc = jnp.zeros_like(words)
+    for s in range(16):
+        acc = acc + ((words >> s) & 1)
+        inner.append(acc)
+    before = lane_cumsum(acc) - acc  # ones in the words before w
+    oc = [before + c for c in inner]  # inclusive one count at 16w + s
+    pos1 = [lane * 16 + (s + 1) for s in range(16)]  # j + 1
     rp = jnp.clip(probe - base - 1, 0, None)  # rebased probe, >= 0
     hp = rp >> lbits
     lp = rp & ((1 << lbits) - 1)
-    # hp clamps to 384 before the int16 narrowing: zc <= 256, so every
-    # b >= 256 already counts all 384 positions -- identical sums, and the
-    # hp > 255 rows are overridden by ``big`` below anyway
-    hp3 = jnp.minimum(hp, EF_HI_BITS)[:, :, None].astype(jnp.int16)
+    # hp clamps to 384: zc <= 256, so every b >= 256 already counts all 384
+    # positions -- identical sums, and the hp > 255 rows are overridden by
+    # ``big`` below anyway
+    hpc = jnp.minimum(hp, EF_HI_BITS)
+
+    def positions(pred):
+        """#stream positions j (over the 24 real words) where pred holds."""
+        n = jnp.zeros_like(words)
+        for s in range(16):
+            n = n + (pred(oc[s], pos1[s]) & word).astype(jnp.int32)
+        return jnp.sum(n, axis=1, keepdims=True)
+
     # count_lt = #lanes with high < hp; count_le = #lanes with high <= hp
-    z_lt = jnp.sum(oc >= pos1 - (hp3 - 1), axis=(1, 2), dtype=jnp.int32)[:, None]
-    z_le = jnp.sum(oc >= pos1 - hp3, axis=(1, 2), dtype=jnp.int32)[:, None]
+    z_lt = positions(lambda o, p: o >= p - (hpc - 1))
+    z_le = positions(lambda o, p: o >= p - hpc)
     big = hp > 255  # beyond the tile's high range: every lane is below
     count_lt = jnp.where(hp <= 0, 0, z_lt - (hp - 1))
     count_lt = jnp.where(big, BLOCK_VALS, count_lt)
     count_le = jnp.where(big, BLOCK_VALS, z_le - hp)
-    lane = jax.lax.broadcasted_iota(jnp.int32, lo.shape, 1)
     mid = jnp.sum(
         ((lane >= count_lt) & (lane < count_le) & (lo < lp)).astype(
             jnp.int32
@@ -91,9 +100,7 @@ def _ef_search_tile(lo, hi_words, lbits, base, probe):
     )
     rank = jnp.where(big, BLOCK_VALS, count_lt + mid)
     rc = jnp.minimum(rank, BLOCK_VALS - 1)
-    sel = jnp.sum(
-        oc <= rc[:, :, None].astype(jnp.int16), axis=(1, 2), dtype=jnp.int32
-    )[:, None]
+    sel = positions(lambda o, p: o <= rc)
     high_r = sel - rc
     low_r = jnp.sum(jnp.where(lane == rc, lo, 0), axis=1, keepdims=True)
     value = base + 1 + ((high_r << lbits) | low_r)
@@ -106,7 +113,7 @@ def _ef_search_kernel(lo_ref, meta_ref, out_ref):
     meta = meta_ref[...]
     value, rank = _ef_search_tile(
         lo,
-        meta[:, :EF_HI_WORDS],
+        meta,
         meta[:, EFMETA_LBITS : EFMETA_LBITS + 1],
         meta[:, EFMETA_BASE : EFMETA_BASE + 1],
         meta[:, EFMETA_PROBE : EFMETA_PROBE + 1],

@@ -33,7 +33,8 @@ def default_backend() -> str:
 
     ``REPRO_BACKEND=numpy|ref|pallas`` overrides the choice -- the knob the
     CI matrix uses to run the whole suite through the jitted device
-    pipeline (``ref``) on CPU-only runners.
+    pipeline (``ref``) on CPU-only runners.  A JAX that fails to
+    initialise raises: serving from the host would hide the device.
     """
     env = os.environ.get("REPRO_BACKEND", "").strip()
     if env:
@@ -42,20 +43,14 @@ def default_backend() -> str:
                 f"REPRO_BACKEND={env!r}: expected numpy, ref, or pallas"
             )
         return env
-    try:
-        if jax.default_backend() in ("tpu", "gpu"):
-            return "pallas"
-    except Exception:
-        pass
-    return "numpy"
+    return "pallas" if jax.default_backend() in ("tpu", "gpu") else "numpy"
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode only off-accelerator: TPU/GPU must COMPILE."""
-    try:
-        return jax.default_backend() not in ("tpu", "gpu")
-    except Exception:
-        return True
+    """Pallas interpret mode only off-accelerator: TPU/GPU must COMPILE.
+
+    Raises, like ``default_backend``, when JAX fails to initialise."""
+    return jax.default_backend() not in ("tpu", "gpu")
 
 
 def _resolve_interpret(interpret) -> bool:
@@ -122,7 +117,9 @@ def decode_block_rows(
     backend: "numpy" (vectorized host decode), "ref" (jnp oracle), or
     "pallas" (the MXU one-hot-matmul kernel; interpret=None auto-selects
     compiled off the default jax backend).  Rows need not be a multiple of
-    BM -- the pallas path pads internally.  Returns [n_rows, 128] int64.
+    BM -- the pallas path pads internally, to a power-of-two row bucket so
+    that every list length reuses one of a few compiled kernel shapes.
+    Returns [n_rows, 128] int64.
     """
     if backend == "numpy":
         return decode_blocks_np(lens_rows, data_rows)
@@ -133,7 +130,7 @@ def decode_block_rows(
         return np.asarray(out).astype(np.int64)
     if backend == "pallas":
         n_rows = lens_rows.shape[0]
-        pad = (-n_rows) % BM
+        pad = max(BM, 1 << (max(n_rows, 1) - 1).bit_length()) - n_rows
         if pad:
             lens_rows = np.concatenate(
                 [lens_rows, np.ones((pad, BLOCK_VALS), np.int32)]

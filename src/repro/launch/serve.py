@@ -66,6 +66,7 @@ from repro.api import EngineConfig, make_query_engine, make_topk_engine
 from repro.core import build_partitioned_index, build_unpartitioned_index
 from repro.core.query_engine import QueryEngine
 from repro.data.postings import make_corpus, make_freqs, make_queries
+from repro.launch.compile_cache import enable_compile_cache
 
 # the one shared percentile implementation (DESIGN.md §12) -- formerly a
 # local helper here plus per-bench copies
@@ -426,6 +427,7 @@ def main() -> None:
         ap.error("--loop and fault injection are separate lanes; "
                  "drop --faults/--fault-prob")
 
+    enable_compile_cache()
     server = None
     if args.metrics_port is not None or args.metrics_dump:
         obs.enable()

@@ -161,9 +161,7 @@ def forward_dst_sharded(params, feats_loc, edges_loc, edge_mask_loc, cfg: GINCon
 def loss_fn_dst_sharded(params, batch, cfg: GINConfig, mesh=None):
     """batch: feats [N,d], edges [2,E] dst-grouped, edge_mask, labels,
     label_mask -- all sharded over every mesh axis (see batch_specs_sharded)."""
-    from repro.compat import get_abstract_mesh
-
-    mesh = mesh or get_abstract_mesh()
+    mesh = mesh or jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return loss_fn(params, batch, cfg)
     axes = _all_axes(mesh)

@@ -21,8 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import get_abstract_mesh
+from jax.sharding import get_abstract_mesh
 
 from .common import dense_init, rms_norm, split_keys
 

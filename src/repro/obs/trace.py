@@ -89,19 +89,17 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dev_ms = None
-        if self._fence is not None:
-            t_fence = time.perf_counter()
-            try:
-                import jax
-
-                jax.block_until_ready(self._fence)
-            except Exception:
-                pass
-            dev_ms = (time.perf_counter() - t_fence) * 1e3
-            self._fence = None
-        t1 = time.perf_counter()
         _TLS.depth = self._depth
+        dev_ms = None
+        fence, self._fence = self._fence, None
+        if fence is not None and exc_type is None:
+            import jax
+
+            t_fence = time.perf_counter()
+            # a failed device computation raises here, out of the span
+            jax.block_until_ready(fence)
+            dev_ms = (time.perf_counter() - t_fence) * 1e3
+        t1 = time.perf_counter()
         dur_ms = (t1 - self._t0) * 1e3
         rec = {
             "kind": "span",

@@ -79,6 +79,7 @@ from repro.core.engine_core import (
     build_locate_dev,
     build_pivot_chunks,
     group_cursors,
+    jit_resident,
     pow2_bucket,
     stage_cursors,
 )
@@ -93,6 +94,12 @@ from repro.kernels.pivot_score.kernel import SCORE_SLOTS
 from repro.kernels.vbyte_decode.kernel import BLOCK_VALS
 from repro.kernels.vbyte_decode.ops import default_interpret
 from repro.ranked.bm25 import topk_select
+
+
+def default_resident() -> str:
+    """What ``resident="auto"`` resolves to: "kernel" on an accelerator,
+    "mirror" where Pallas only interprets."""
+    return "mirror" if default_interpret() else "kernel"
 
 
 class TopKEngine:
@@ -188,7 +195,7 @@ class TopKEngine:
         self.bounds = r.block_bounds().astype(np.float64)  # [nb]
         self.list_ub = r.list_ub.astype(np.float64)        # [n_lists]
         if resident == "auto":
-            resident = "mirror" if default_interpret() else "kernel"
+            resident = default_resident()
         if resident not in ("mirror", "kernel"):
             raise ValueError(f"unknown resident mode {resident!r}")
         self.resident = resident
@@ -223,6 +230,7 @@ class TopKEngine:
         # dispatch, the resident row scorer, and the device theta round
         self._pivot_score_fn = None
         self._rowscore_fn = None
+        self._idf_blk = None
         self._theta_fn = None
         self._scache_rows = np.zeros(0, np.int64)  # sorted hot rows
         self._scache = np.zeros((0, BLOCK_VALS), np.float32)
@@ -475,24 +483,30 @@ class TopKEngine:
         (one upload of the gathered tiles per call); this keeps the whole
         sidecar resident and gathers ON DEVICE, so a row-scoring round is
         one dispatch whose only host traffic is the fetched scores."""
-        import jax
         import jax.numpy as jnp
 
         from repro.kernels.bm25_score.ops import score_rows_graph
 
-        rdev = self.ranked.dev
-        idf_dev = jnp.asarray(self.ranked.idf[self.lob])
+        res = dict(vars(self.ranked.dev), idf_blk=self._idf_blk_dev())
         backend, interpret = self.backend, self.interpret
         k1p1 = float(self.k1p1)
 
-        def fn(rows):
+        def fn(r, rows):
             return score_rows_graph(
-                rdev.freq_lens[rows], rdev.freq_data[rows],
-                rdev.norm_q[rows].astype(jnp.int32), idf_dev[rows],
-                rdev.norm_table, k1p1, backend, interpret,
+                r["freq_lens"][rows], r["freq_data"][rows],
+                r["norm_q"][rows].astype(jnp.int32), r["idf_blk"][rows],
+                r["norm_table"], k1p1, backend, interpret,
             )
 
-        return jax.jit(fn)
+        return jit_resident(fn, res)
+
+    def _idf_blk_dev(self):
+        """[n_blocks] f32 idf of each block's list, on the device once."""
+        if self._idf_blk is None:
+            import jax.numpy as jnp
+
+            self._idf_blk = jnp.asarray(self.ranked.idf[self.lob])
+        return self._idf_blk
 
     def _rowscore_dev(self, mrows: np.ndarray):
         """ONE resident row-scoring dispatch (pow2 row bucket, padding
@@ -543,19 +557,16 @@ class TopKEngine:
     def _build_pivot_fn(self, pc):
         """Jitted gather -> pivot_graph over ONE arena's resident chunk
         tiles (the global ones, or a shard's)."""
-        import jax
-
         from repro.core.engine_core import pivot_graph
 
-        qb_dev, nblk_dev = pc.dev.qb, pc.dev.nblk
         backend, interpret = self.backend, self.interpret
 
-        def fn(rows, qmins):
+        def fn(c, rows, qmins):
             return pivot_graph(
-                qb_dev[rows], qmins, nblk_dev[rows], backend, interpret
+                c["qb"][rows], qmins, c["nblk"][rows], backend, interpret
             )
 
-        return jax.jit(fn)
+        return jit_resident(fn, vars(pc.dev))
 
     def _pivot_dev_on(self, fn, rows, qmins):
         """Device dispatch of one arena's jitted pivot fn: pow2 cursor
@@ -592,26 +603,27 @@ class TopKEngine:
         of every cursor in-graph, so the lane-exact candidate filter that
         used to need a second kernel round-trip rides back with the
         pivot fetch."""
-        import jax
         import jax.numpy as jnp
 
+        from repro.core.arena import to_i32
         from repro.core.engine_core import pivot_score_graph
 
-        qb_dev, nblk_dev = pc.dev.qb, pc.dev.nblk
-        base_dev = jnp.asarray(pc.base.astype(np.int32))
-        rdev = self.ranked.dev
-        idf_dev = jnp.asarray(self.ranked.idf[self.lob])
+        res = dict(
+            vars(self.ranked.dev), **vars(pc.dev),
+            base=jnp.asarray(to_i32(pc.base, "chunk base")),
+            idf_blk=self._idf_blk_dev(),
+        )
         backend, interpret = self.backend, self.interpret
         k1p1 = float(self.k1p1)
 
-        def fn(rows, qmins):
+        def fn(r, rows, qmins):
             return pivot_score_graph(
-                qb_dev[rows], qmins, nblk_dev[rows], base_dev[rows],
-                rdev.freq_lens, rdev.freq_data, rdev.norm_q, idf_dev,
-                rdev.norm_table, k1p1, SCORE_SLOTS, backend, interpret,
+                r["qb"][rows], qmins, r["nblk"][rows], r["base"][rows],
+                r["freq_lens"], r["freq_data"], r["norm_q"], r["idf_blk"],
+                r["norm_table"], k1p1, SCORE_SLOTS, backend, interpret,
             )
 
-        return jax.jit(fn)
+        return jit_resident(fn, res)
 
     def _fusable_cursors(self, rows, cur_ij, theta, pc) -> np.ndarray:
         """FUSED-dispatch routing mask, per pivot cursor (§13).
@@ -960,34 +972,44 @@ class TopKEngine:
         (the global one, or a shard's sub-arena).  Both graph halves come
         from the shared single-source helpers (``locate_graph`` via
         ``build_locate_dev``, ``score_probe_graph``)."""
-        import jax
         import jax.numpy as jnp
 
         from repro.kernels.bm25_score.ops import score_probe_graph
 
-        dev, rdev = arena.dev, ranked.dev
-        lob = arena.part_list[arena.part_of_block]
-        lob_dev = jnp.asarray(lob.astype(np.int32))
+        res = self._contrib_resident(arena, ranked)
         locate = build_locate_dev(arena)
         backend, interpret = self.backend, self.interpret
         k1p1 = float(self.k1p1)
 
         multi = arena.block_codec is not None
 
-        def fn(terms, probes):
-            rows, pe, past = locate(terms, probes)
+        def fn(r, terms, probes):
+            rows, pe, past = locate(r, terms, probes)
             # multi-codec arenas compact the SVB doc tiles: gather through
             # codec_row (the host bucketing only sends SVB-block cursors)
-            sr = dev.codec_row[rows] if multi else rows
+            sr = r["codec_row"][rows] if multi else rows
             contrib = score_probe_graph(
-                dev.lens[sr], dev.data[sr], rdev.freq_lens[rows],
-                rdev.freq_data[rows], rdev.norm_q[rows].astype(jnp.int32),
-                dev.block_base[rows], pe, rdev.idf[lob_dev[rows]],
-                rdev.norm_table, k1p1, backend, interpret,
+                r["lens"][sr], r["data"][sr], r["freq_lens"][rows],
+                r["freq_data"][rows], r["norm_q"][rows].astype(jnp.int32),
+                r["block_base"][rows], pe, r["idf"][r["lob"][rows]],
+                r["norm_table"], k1p1, backend, interpret,
             )
             return jnp.where(past, jnp.float32(0.0), contrib)
 
-        return jax.jit(fn)
+        return jit_resident(fn, res)
+
+    @staticmethod
+    def _contrib_resident(arena, ranked) -> dict:
+        """The resident arrays of one arena's contribution fns."""
+        import jax.numpy as jnp
+
+        from repro.core.arena import to_i32
+
+        lob = arena.part_list[arena.part_of_block]
+        return dict(
+            vars(arena.dev), **vars(ranked.dev),
+            lob=jnp.asarray(to_i32(lob, "lob")),
+        )
 
     def _build_ef_jax_fn(self, arena, ranked):
         """Jitted locate -> EF-NextGEQ -> score-row -> lane-select over one
@@ -999,30 +1021,27 @@ class TopKEngine:
         rank -- per-posting arithmetic identical to ``score_probe_graph``,
         hence bit-identical contributions.
         """
-        import jax
         import jax.numpy as jnp
 
         from repro.core.engine_core import ef_search_graph
         from repro.kernels.bm25_score.ops import score_rows_graph
 
-        dev, rdev = arena.dev, ranked.dev
-        lob = arena.part_list[arena.part_of_block]
-        lob_dev = jnp.asarray(lob.astype(np.int32))
+        res = self._contrib_resident(arena, ranked)
         locate = build_locate_dev(arena)
         backend, interpret = self.backend, self.interpret
         k1p1 = float(self.k1p1)
 
-        def fn(terms, probes):
-            rows, pe, past = locate(terms, probes)
-            er = dev.codec_row[rows]
+        def fn(r, terms, probes):
+            rows, pe, past = locate(r, terms, probes)
+            er = r["codec_row"][rows]
             value, rank_in = ef_search_graph(
-                dev.ef_lo[er], dev.ef_hi[er], dev.ef_lbits[er],
-                dev.block_base[rows], pe, backend, interpret,
+                r["ef_lo"][er], r["ef_hi"][er], r["ef_lbits"][er],
+                r["block_base"][rows], pe, backend, interpret,
             )
             row_scores = score_rows_graph(
-                rdev.freq_lens[rows], rdev.freq_data[rows],
-                rdev.norm_q[rows].astype(jnp.int32),
-                rdev.idf[lob_dev[rows]], rdev.norm_table, k1p1, backend,
+                r["freq_lens"][rows], r["freq_data"][rows],
+                r["norm_q"][rows].astype(jnp.int32),
+                r["idf"][r["lob"][rows]], r["norm_table"], k1p1, backend,
                 interpret,
             )
             rc = jnp.minimum(rank_in, BLOCK_VALS - 1)
@@ -1032,7 +1051,7 @@ class TopKEngine:
             hit = (value == pe) & ~past
             return jnp.where(hit, contrib, jnp.float32(0.0))
 
-        return jax.jit(fn)
+        return jit_resident(fn, res)
 
     # largest single device dispatch: bigger batches are chunked to this
     # fixed bucket so every chunk reuses ONE jit trace and the gathered
@@ -1136,9 +1155,6 @@ class TopKEngine:
 
     @property
     def _use_device(self) -> bool:
-        if self.sharded is not None:
-            # routing-metadata-only check: must not force the shard slices
-            return self.backend in ("ref", "pallas") and self.sharded.all_device_ok
         return self.core.use_device
 
     def contributions(self, terms, docs) -> np.ndarray:

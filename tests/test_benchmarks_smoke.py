@@ -74,6 +74,9 @@ def test_run_json_appends_history(tmp_path, monkeypatch, capsys):
     from benchmarks import run as bench_run
 
     monkeypatch.chdir(tmp_path)
+    # main() turns the compile cache on; with the variable set it leaves
+    # this process's jax config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     monkeypatch.setattr(
         sys, "argv",
         ["benchmarks.run", "--smoke", "--json", "--only", "table5"],
@@ -109,6 +112,9 @@ def test_run_json_migrates_pre_history_file(tmp_path, monkeypatch, capsys):
     from benchmarks import run as bench_run
 
     monkeypatch.chdir(tmp_path)
+    # main() turns the compile cache on; with the variable set it leaves
+    # this process's jax config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     old = {"profile": "quick",
            "records": [{"name": "legacy_record", "us_per_call": 1.0,
                         "derived": ""}]}

@@ -17,7 +17,6 @@ def test_compressed_psum_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import sys, json
         sys.path.insert(0, "src")
-        import repro  # installs jax version-compat backfills (repro.compat)
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import AxisType
         from repro.optim.compress import compressed_psum, ef_init

@@ -540,7 +540,6 @@ def test_shard_map_faults_multidevice_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys, json, tempfile
         sys.path.insert(0, "src")
-        import repro  # installs jax version-compat backfills
         import numpy as np
         import jax
         from repro.checkpoint import CheckpointManager

@@ -26,7 +26,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, dataclasses
 sys.path.insert(0, "src")
-import repro  # installs jax version-compat backfills (repro.compat)
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, AxisType
 """
