@@ -1,0 +1,14 @@
+"""Idle device time while the ranked engine ran its Block-Max pivot (qmin
+reduction, pivot dispatches, lane-exact candidate filter, candidate
+union), % of the traced window: the idle pieces under ``repro.pivot``,
+inclusive (``bench/harness/program_trace.py``).  Read in the cells whose
+operation is ``topk``."""
+
+from harness import program_trace
+
+
+def read(run):
+    if run.operation != "topk":
+        return None
+    g = program_trace.for_run(run)
+    return None if g is None else g.share(["repro.pivot"])

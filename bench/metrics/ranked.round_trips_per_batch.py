@@ -1,0 +1,11 @@
+"""Blocking device-to-host fetches per ``topk_batch`` call over the window
+(the ranked engine's ``device_round_trips`` counter over its ``batches``).
+Read in the cells whose operation is ``topk``."""
+
+
+def read(run):
+    if run.operation != "topk" or not run.stats.get("batches"):
+        return None
+    if "device_round_trips" not in run.stats:
+        return None
+    return run.stats["device_round_trips"] / run.stats["batches"]

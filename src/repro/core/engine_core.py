@@ -481,7 +481,8 @@ class EngineCore:
         # CounterDict default mirrors increments onto obs counters when the
         # observability layer is armed (compat shim, DESIGN.md §12)
         self.stats = stats if stats is not None else obs.CounterDict("engine")
-        for key in ("decoded_rows", "kernel_calls", "cache_hits", "evictions"):
+        for key in ("decoded_rows", "kernel_calls", "cache_hits", "evictions",
+                    "device_round_trips"):
             self.stats.setdefault(key, 0)
         self.cache: OrderedDict = OrderedDict()
         self.cache_nbytes = 0
@@ -746,12 +747,17 @@ class EngineCore:
         import jax.numpy as jnp
 
         n = len(terms)
-        tp, pp = stage_cursors(terms, probes, self.arena.stride, pow2_bucket(n))
+        with obs.span("stage"):
+            tp, pp = stage_cursors(
+                terms, probes, self.arena.stride, pow2_bucket(n)
+            )
         value, rank = fn(jnp.asarray(tp), jnp.asarray(pp))
-        return (
-            np.asarray(value)[:n].astype(np.int64),
-            np.asarray(rank)[:n].astype(np.int64),
-        )
+        self.stats["device_round_trips"] += 1
+        with obs.span("fetch"):
+            return (
+                np.asarray(value)[:n].astype(np.int64),
+                np.asarray(rank)[:n].astype(np.int64),
+            )
 
     def search_jax(self, terms, probes):
         """Device fused pipeline, jitted end-to-end over the resident arena.
@@ -776,12 +782,15 @@ class EngineCore:
             return self._dispatch_jax(self._jax_fn, terms, probes)
         from repro.core.arena import CODEC_EF
 
-        terms = np.asarray(terms, dtype=np.int64)
-        probes = np.asarray(probes, dtype=np.int64)
-        pc = np.clip(probes, 0, a.stride - 1)
-        k = np.searchsorted(a.block_keys, pc + terms * a.stride, side="left")
-        codec = a.block_codec[np.minimum(k, a.n_blocks - 1)]
-        ef_j = np.nonzero(codec == CODEC_EF)[0]
+        with obs.span("codec_split"):
+            terms = np.asarray(terms, dtype=np.int64)
+            probes = np.asarray(probes, dtype=np.int64)
+            pc = np.clip(probes, 0, a.stride - 1)
+            k = np.searchsorted(
+                a.block_keys, pc + terms * a.stride, side="left"
+            )
+            codec = a.block_codec[np.minimum(k, a.n_blocks - 1)]
+            ef_j = np.nonzero(codec == CODEC_EF)[0]
         n = len(terms)
         if not len(ef_j):
             return self._dispatch_jax(self._jax_fn, terms, probes)

@@ -182,6 +182,8 @@ class QueryEngine:
                 "fused_batches": 0,
                 "grouped_cursors": 0,
                 "sharded_batches": 0,
+                "batches": 0,  # intersect_batch calls
+                "device_round_trips": 0,  # blocking device->host fetches
             },
             engine="query",
         )
@@ -390,7 +392,8 @@ class QueryEngine:
             # duplicate would gather + decode its block row again.  Grouping
             # runs BEFORE shard routing, so duplicates collapse across the
             # whole batch whatever shard they land on.
-            g = group_cursors(terms, probes, self.arena.stride)
+            with obs.span("group_cursors"):
+                g = group_cursors(terms, probes, self.arena.stride)
             if g is not None:
                 idx, inv = g
                 self.stats["grouped_cursors"] += n - len(idx)
@@ -526,6 +529,7 @@ class QueryEngine:
         term (ascending size) filters them with one vectorized membership
         pass across the WHOLE batch.
         """
+        self.stats["batches"] += 1
         nq = len(queries)
         sizes = self.index.list_sizes
         order = [sorted(map(int, q), key=lambda t: int(sizes[t])) for q in queries]

@@ -41,7 +41,6 @@ from .trace import (
     event,
     events,
     now,
-    profile,
     span,
     timer,
 )
@@ -72,7 +71,6 @@ __all__ = [
     "histogram",
     "now",
     "observe",
-    "profile",
     "render_prometheus",
     "reset",
     "set_gauge",

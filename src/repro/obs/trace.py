@@ -6,10 +6,11 @@ a bounded ring and observes the duration into the ``span_ms`` histogram
 (labelled by span name).  When disarmed, ``span()`` returns a shared
 no-op singleton -- no allocation, no clock read, no lock.
 
-Device time is strictly opt-in: ``sp.fence(x)`` stores a jax array to
-``block_until_ready`` at span exit, and the fence only fires when
-tracing is ON, so instrumentation can never add a host sync to an
-uninstrumented run (the sync_audit ratchet stays flat).
+An armed span is also a host span on the ``jax.profiler`` timeline,
+named ``repro.<name>``: a profiler trace of an armed run shows the
+program's phases on the same clock as the device's operations.  Spans
+never wait on the device, so instrumentation adds no host sync (the
+sync_audit ratchet stays flat).
 
 ``now()`` is the sanctioned raw clock for code that needs a timestamp
 across scopes; the ``obs-timers`` idiom-lint rule steers the rest of
@@ -33,7 +34,6 @@ __all__ = [
     "event",
     "events",
     "now",
-    "profile",
     "span",
     "timer",
 ]
@@ -42,6 +42,7 @@ TRACE_CAPACITY = 4096
 _RING: deque = deque(maxlen=TRACE_CAPACITY)
 _EPOCH = time.perf_counter()
 _TLS = threading.local()
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported on first use
 
 
 def now() -> float:
@@ -66,40 +67,43 @@ def event(name: str, **fields) -> None:
         _RING.append(rec)
 
 
-class Span:
-    """Armed span: wall time always, device time via opt-in fence()."""
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (a plain
+    no-op context where jax is not importable); jax is imported on the
+    first armed span, once."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = lambda _name: contextlib.nullcontext()  # noqa: E731
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION("repro." + name)
 
-    __slots__ = ("name", "labels", "_t0", "_depth", "_fence")
+
+class Span:
+    """Armed span: wall time into the ring and ``span_ms``, and a profiler
+    host span over the same stretch."""
+
+    __slots__ = ("name", "labels", "_t0", "_depth", "_annot")
 
     def __init__(self, name: str, labels: dict):
         self.name = name
         self.labels = labels
-        self._fence = None
-
-    def fence(self, x) -> None:
-        """Block on ``x`` at span exit so the span covers device time.
-        Only reachable when tracing is ON -- never fences a cold run."""
-        self._fence = x
 
     def __enter__(self):
         depth = getattr(_TLS, "depth", 0)
         _TLS.depth = depth + 1
         self._depth = depth
+        self._annot = _annotation(self.name)
+        self._annot.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        _TLS.depth = self._depth
-        dev_ms = None
-        fence, self._fence = self._fence, None
-        if fence is not None and exc_type is None:
-            import jax
-
-            t_fence = time.perf_counter()
-            # a failed device computation raises here, out of the span
-            jax.block_until_ready(fence)
-            dev_ms = (time.perf_counter() - t_fence) * 1e3
         t1 = time.perf_counter()
+        self._annot.__exit__(exc_type, exc, tb)
+        _TLS.depth = self._depth
         dur_ms = (t1 - self._t0) * 1e3
         rec = {
             "kind": "span",
@@ -109,8 +113,6 @@ class Span:
             "depth": self._depth,
             "thread": threading.current_thread().name,
         }
-        if dev_ms is not None:
-            rec["fence_ms"] = dev_ms
         if self.labels:
             rec.update(self.labels)
         _RING.append(rec)
@@ -123,9 +125,6 @@ class _NullSpan:
     """Disarmed singleton: every method is a constant no-op."""
 
     __slots__ = ()
-
-    def fence(self, x) -> None:
-        pass
 
     def __enter__(self):
         return self
@@ -172,21 +171,3 @@ class Timer:
 def timer(name: str, **labels) -> Timer:
     """Wall-clock timer; histogram names take a ``_ms`` suffix by convention."""
     return Timer(name, labels)
-
-
-@contextlib.contextmanager
-def profile(logdir: str = "/tmp/repro_profile"):
-    """Wrap ``jax.profiler.trace`` when jax is importable and the layer is
-    armed; degrades to a plain no-op context otherwise."""
-    if not _m.enabled():
-        yield
-        return
-    try:
-        import jax
-
-        ctx = jax.profiler.trace(logdir)
-    except Exception:
-        yield
-        return
-    with ctx:
-        yield

@@ -186,6 +186,7 @@ class TopKEngine:
                 "score_evictions": 0,  # hot-block score cache flushes (rows)
                 "fused_pivot_chunks": 0,  # cursors through pivot_score (§13)
                 "theta_device_rounds": 0,  # device-carried theta rounds
+                "device_round_trips": 0,  # blocking device->host fetches
             },
             engine="topk",
         )
@@ -412,9 +413,11 @@ class TopKEngine:
         shuffles.  Each round fetches here exactly once per MAX_BUCKET
         chunk, after the whole round's graph has been dispatched.
         """
+        self.stats["device_round_trips"] += 1
         out = []
-        for a in arrays:
-            out.append(np.asarray(a))
+        with obs.span("fetch", path="ranked"):
+            for a in arrays:
+                out.append(np.asarray(a))
         return out
 
     def _cache_lookup(self, urows: np.ndarray):
@@ -1353,45 +1356,48 @@ class TopKEngine:
         self._flat_init()
         a, core = self.arena, self.core
         nq = len(specs)
-        t_chunks, d_chunks, cuts = [], [], [0]
-        for terms, _, docs in specs:
-            t_chunks.append(np.repeat(terms, len(docs)))
-            d_chunks.append(np.tile(docs, len(terms)))
-            cuts.append(cuts[-1] + len(terms) * len(docs))
-        if cuts[-1] == 0:
-            return [
-                (np.zeros(0, np.int64), np.zeros(0, np.float64))
-                for _ in specs
-            ], (None if theta is None else theta.copy())
-        t_rep = np.concatenate(t_chunks)
-        d_til = np.concatenate(d_chunks)
-        pos = np.searchsorted(core.flat_keys, d_til + t_rep * a.stride, "left")
-        past = pos >= core.lane_end[t_rep + 1]
-        member = (core.flat_vals[pos] == d_til) & ~past
-        row = np.minimum(pos, a.n_blocks * BLOCK_VALS - 1) >> 7
+        with obs.span("membership", path="ranked"):
+            t_chunks, d_chunks, cuts = [], [], [0]
+            for terms, _, docs in specs:
+                t_chunks.append(np.repeat(terms, len(docs)))
+                d_chunks.append(np.tile(docs, len(terms)))
+                cuts.append(cuts[-1] + len(terms) * len(docs))
+            if cuts[-1] == 0:
+                return [
+                    (np.zeros(0, np.int64), np.zeros(0, np.float64))
+                    for _ in specs
+                ], (None if theta is None else theta.copy())
+            t_rep = np.concatenate(t_chunks)
+            d_til = np.concatenate(d_chunks)
+            pos = np.searchsorted(
+                core.flat_keys, d_til + t_rep * a.stride, "left"
+            )
+            past = pos >= core.lane_end[t_rep + 1]
+            member = (core.flat_vals[pos] == d_til) & ~past
+            row = np.minimum(pos, a.n_blocks * BLOCK_VALS - 1) >> 7
 
-        need_ub = theta is not None
-        mems, ubs = [], []
-        for i, (terms, mult, docs) in enumerate(specs):
-            T, D = len(terms), len(docs)
-            if T == 0 or D == 0:
-                mems.append(np.zeros((T, D), bool))
-                ubs.append(np.zeros(D, np.float64))
-                continue
-            sl = slice(cuts[i], cuts[i + 1])
-            mem = member[sl].reshape(T, D)
-            mems.append(mem)
-            if need_ub:
-                ubs.append(
-                    (
-                        mult[:, None]
-                        * np.where(
-                            mem, self.bounds[row[sl].reshape(T, D)], 0.0
-                        )
-                    ).sum(axis=0)
-                )
-            else:
-                ubs.append(None)
+            need_ub = theta is not None
+            mems, ubs = [], []
+            for i, (terms, mult, docs) in enumerate(specs):
+                T, D = len(terms), len(docs)
+                if T == 0 or D == 0:
+                    mems.append(np.zeros((T, D), bool))
+                    ubs.append(np.zeros(D, np.float64))
+                    continue
+                sl = slice(cuts[i], cuts[i + 1])
+                mem = member[sl].reshape(T, D)
+                mems.append(mem)
+                if need_ub:
+                    ubs.append(
+                        (
+                            mult[:, None]
+                            * np.where(
+                                mem, self.bounds[row[sl].reshape(T, D)], 0.0
+                            )
+                        ).sum(axis=0)
+                    )
+                else:
+                    ubs.append(None)
 
         def pairs_for(sels: list[np.ndarray]):
             """Member-pair segments of the selected doc slots: per query
@@ -1431,20 +1437,23 @@ class TopKEngine:
             via ONE batched contribution dispatch over the member pairs."""
             idx_l, col_l, w_l, g_idx = pairs_for(sels)
             self.stats["scored_pairs"] += len(g_idx)
-            if self.resident == "kernel":
-                # member pairs pin exact (row, lane) coordinates, so the
-                # batch's contributions cost ONE all-lane kernel pass over
-                # the UNIQUE touched rows -- not one gathered cursor per
-                # pair: many candidates share a hot block, and the block is
-                # decoded+scored once however many pairs land in it
-                g_pos = pos[g_idx]
-                rows_n, lanes = g_pos >> 7, g_pos & (BLOCK_VALS - 1)
-                urows, inv = np.unique(rows_n, return_inverse=True)
-                row_scores = self._score_rows_batch(urows)
-                contrib = row_scores[inv, lanes]
-            else:
-                contrib = core.flat_scores[pos[g_idx]]
-            return accumulate(idx_l, col_l, w_l, sels, contrib)
+            with obs.span("score_rows", path="ranked"):
+                if self.resident == "kernel":
+                    # member pairs pin exact (row, lane) coordinates, so
+                    # the batch's contributions cost ONE all-lane kernel
+                    # pass over the UNIQUE touched rows -- not one gathered
+                    # cursor per pair: many candidates share a hot block,
+                    # and the block is decoded+scored once however many
+                    # pairs land in it
+                    g_pos = pos[g_idx]
+                    rows_n, lanes = g_pos >> 7, g_pos & (BLOCK_VALS - 1)
+                    urows, inv = np.unique(rows_n, return_inverse=True)
+                    row_scores = self._score_rows_batch(urows)
+                    contrib = row_scores[inv, lanes]
+                else:
+                    contrib = core.flat_scores[pos[g_idx]]
+            with obs.span("accumulate", path="ranked"):
+                return accumulate(idx_l, col_l, w_l, sels, contrib)
 
         if theta is None or k is None:
             sels = [np.ones(len(docs), bool) for _, _, docs in specs]
@@ -1455,7 +1464,6 @@ class TopKEngine:
 
         # ---- round A: the max(4k, 64) highest-UB docs, scored exactly
         # (argpartition: ANY k-superset works here, order does not matter)
-        obs.count("ranked_rescore_rounds", 2)
         cap = max(4 * k, 64)
         sel_a = []
         for i, (_, _, docs) in enumerate(specs):
@@ -1476,39 +1484,40 @@ class TopKEngine:
         idx_l, col_l, w_l, g_idx = pairs_for(sel_a)
         self.stats["scored_pairs"] += len(g_idx)
         mask_b = None
-        if self.resident == "kernel":
-            g_pos = pos[g_idx]
-            rows_n, lanes = g_pos >> 7, g_pos & (BLOCK_VALS - 1)
-            urows, inv = np.unique(rows_n, return_inverse=True)
-            out_u, hit = self._cache_lookup(urows)
-            miss = ~hit
-            mrows = urows[miss]
-            if (
-                self.sharded is None
-                and self.core.use_device
-                and 0 < len(mrows) <= self.MAX_BUCKET
-            ):
-                mask_b = self._theta_round_dev(
-                    specs, sel_a, cap, k, theta, ubs,
-                    idx_l, col_l, w_l, out_u, hit, inv, lanes, miss, mrows,
-                )
-            elif miss.any():
-                self.stats["scored_rows"] += len(mrows)
-                scored = self._score_miss_rows(mrows)
-                out_u[miss] = scored
-                self._cache_merge(mrows, scored)
-            contrib = out_u[inv, lanes]
-        else:
-            contrib = core.flat_scores[pos[g_idx]]
-        scores_a = accumulate(idx_l, col_l, w_l, sel_a, contrib)
-
-        # ---- raise theta to the k-th true score of round A (exact f64:
-        # the returned theta2 is bit-identical on every path)
-        theta2 = theta.copy()
-        for i, sc in enumerate(scores_a):
-            if len(sc) >= k:
-                kth = np.partition(sc, len(sc) - k)[len(sc) - k]
-                theta2[i] = max(theta2[i], kth)
+        with obs.span("score_rows", path="ranked"):
+            if self.resident == "kernel":
+                g_pos = pos[g_idx]
+                rows_n, lanes = g_pos >> 7, g_pos & (BLOCK_VALS - 1)
+                urows, inv = np.unique(rows_n, return_inverse=True)
+                out_u, hit = self._cache_lookup(urows)
+                miss = ~hit
+                mrows = urows[miss]
+                if (
+                    self.sharded is None
+                    and self.core.use_device
+                    and 0 < len(mrows) <= self.MAX_BUCKET
+                ):
+                    mask_b = self._theta_round_dev(
+                        specs, sel_a, cap, k, theta, ubs, idx_l, col_l,
+                        w_l, out_u, hit, inv, lanes, miss, mrows,
+                    )
+                elif miss.any():
+                    self.stats["scored_rows"] += len(mrows)
+                    scored = self._score_miss_rows(mrows)
+                    out_u[miss] = scored
+                    self._cache_merge(mrows, scored)
+                contrib = out_u[inv, lanes]
+            else:
+                contrib = core.flat_scores[pos[g_idx]]
+        with obs.span("accumulate", path="ranked"):
+            scores_a = accumulate(idx_l, col_l, w_l, sel_a, contrib)
+            # ---- raise theta to the k-th true score of round A (exact
+            # f64: the returned theta2 is bit-identical on every path)
+            theta2 = theta.copy()
+            for i, sc in enumerate(scores_a):
+                if len(sc) >= k:
+                    kth = np.partition(sc, len(sc) - k)[len(sc) - k]
+                    theta2[i] = max(theta2[i], kth)
 
         # ---- round B: remaining docs whose UB clears the raised theta.
         # The device mask keeps a superset of {UB >= exact theta2} (its
@@ -1556,6 +1565,10 @@ class TopKEngine:
         """Exact BM25 top-k of each query; (docIDs, f64 scores) per query,
         sorted by (score desc, docID asc) -- identical to the exhaustive
         oracle, including the tie-break."""
+        with obs.span("topk_batch", path="ranked"):
+            return self._topk_batch(queries, k)
+
+    def _topk_batch(self, queries, k):
         a = self.arena
         self.stats["batches"] += 1
         specs = [self._query_spec(q) for q in queries]
@@ -1605,22 +1618,23 @@ class TopKEngine:
             with obs.span("pivot", path="ranked", resident="kernel"):
                 cand_docs = self._pivot_candidates(specs, theta)
                 final_specs = []
-                for i, (terms, mult) in enumerate(specs):
-                    if len(terms) == 0:
-                        final_specs.append(
-                            (terms, mult, np.zeros(0, np.int64))
+                with obs.span("union", path="ranked"):
+                    for i, (terms, mult) in enumerate(specs):
+                        if len(terms) == 0:
+                            final_specs.append(
+                                (terms, mult, np.zeros(0, np.int64))
+                            )
+                            continue
+                        cand_chunks = [seeds[i]] if i in seeds else []
+                        if len(cand_docs[i]):
+                            cand_chunks.append(cand_docs[i])
+                        cand = (
+                            np.unique(np.concatenate(cand_chunks))
+                            if cand_chunks
+                            else np.zeros(0, np.int64)
                         )
-                        continue
-                    cand_chunks = [seeds[i]] if i in seeds else []
-                    if len(cand_docs[i]):
-                        cand_chunks.append(cand_docs[i])
-                    cand = (
-                        np.unique(np.concatenate(cand_chunks))
-                        if cand_chunks
-                        else np.zeros(0, np.int64)
-                    )
-                    self.stats["candidates"] += len(cand)
-                    final_specs.append((terms, mult, cand))
+                        self.stats["candidates"] += len(cand)
+                        final_specs.append((terms, mult, cand))
             with obs.span("rescore", path="ranked"):
                 final_scored, theta2 = self._score_specs(final_specs, theta, k)
             self._note_theta(theta2)

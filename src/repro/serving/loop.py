@@ -147,39 +147,40 @@ class AsyncTopKServer:
 
     def _run_wave(self) -> bool:
         """Form and serve one wave; False when the queue was idle."""
-        t_form = self.clock()
-        batch, expired, bucket = self.former.take(t_form)
-        if self.former.depth < self.former.max_queue:
-            self._space.set()
-        for req in expired:
-            self.stats["expired"] += 1
-            obs.count("serve_deadline_misses", kind="expired")
-            self._resolve(req, ServeResult(
-                *_EMPTY, expired=True,
-                wait_s=t_form - req.enqueued, service_s=0.0,
-            ))
-        if not batch:
-            return False
-        queries = [req.query for req in batch]
-        if self.pad_waves and bucket > len(batch):
-            self.stats["padded_queries"] += bucket - len(batch)
-            queries += [[] for _ in range(bucket - len(batch))]
-        obs.observe("serve_wave_occupancy", len(batch) / max(bucket, 1))
-        with obs.timer("serve_wave_ms", engine="topk"):
-            outs = self.engine.topk_batch(queries, self.k)
-        t_done = self.clock()
-        for req, (docs, scores) in zip(batch, outs):
-            self.stats["served"] += 1
-            if req.deadline < t_done:
-                self.stats["late"] += 1
-                obs.count("serve_deadline_misses", kind="late")
-            self._resolve(req, ServeResult(
-                docs, scores, expired=False,
-                wait_s=t_form - req.enqueued,
-                service_s=t_done - t_form,
-            ))
-        obs.set_gauge("serve_queue_depth", self.former.depth)
-        return True
+        with obs.span("serve.wave"):
+            t_form = self.clock()
+            batch, expired, bucket = self.former.take(t_form)
+            if self.former.depth < self.former.max_queue:
+                self._space.set()
+            for req in expired:
+                self.stats["expired"] += 1
+                obs.count("serve_deadline_misses", kind="expired")
+                self._resolve(req, ServeResult(
+                    *_EMPTY, expired=True,
+                    wait_s=t_form - req.enqueued, service_s=0.0,
+                ))
+            if not batch:
+                return False
+            queries = [req.query for req in batch]
+            if self.pad_waves and bucket > len(batch):
+                self.stats["padded_queries"] += bucket - len(batch)
+                queries += [[] for _ in range(bucket - len(batch))]
+            obs.observe("serve_wave_occupancy", len(batch) / max(bucket, 1))
+            with obs.timer("serve_wave_ms", engine="topk"):
+                outs = self.engine.topk_batch(queries, self.k)
+            t_done = self.clock()
+            for req, (docs, scores) in zip(batch, outs):
+                self.stats["served"] += 1
+                if req.deadline < t_done:
+                    self.stats["late"] += 1
+                    obs.count("serve_deadline_misses", kind="late")
+                self._resolve(req, ServeResult(
+                    docs, scores, expired=False,
+                    wait_s=t_form - req.enqueued,
+                    service_s=t_done - t_form,
+                ))
+            obs.set_gauge("serve_queue_depth", self.former.depth)
+            return True
 
     async def serve_forever(self) -> None:
         """Run waves until :meth:`close`.  Between waves the loop yields
