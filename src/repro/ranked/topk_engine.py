@@ -23,14 +23,15 @@ values, so it is exact):
    with score >= theta provably survives through each block containing it.
 
 3. **Rescore + select** (threshold+compact, two rounds).  ONE membership
-   pass (a single searchsorted over the flat lane keys) resolves every
-   (term, candidate) pair and yields doc-aligned upper bounds from the
-   block-max sidecar.  Round A exact-scores the highest-UB docs and raises
-   theta to their k-th true score; round B scores only the remaining docs
-   whose UB clears the raised theta.  Member-pair contributions come from
-   the impact mirror (``resident="mirror"``) or the fused decode+score
-   kernel over the unique touched rows (``resident="kernel"``, the
-   HBM-resident accelerator path).  Per-doc sums accumulate in float64 --
+   pass (per query term, a searchsorted of the candidates in that term's
+   own lane of the flat lane keys) resolves every (term, candidate) pair
+   and yields doc-aligned upper bounds from the block-max sidecar.  Round
+   A exact-scores the highest-UB docs and raises theta to their k-th true
+   score; round B scores only the remaining docs whose UB clears the
+   raised theta.  Member-pair contributions come from the impact mirror
+   (``resident="mirror"``) or the fused decode+score kernel over the
+   unique touched rows (``resident="kernel"``, the HBM-resident
+   accelerator path).  Per-doc sums accumulate in float64 --
    exact and order-free, because the f32 contributions span far less than
    f64's 29 bits of headroom -- then (score desc, docID asc) cuts to k.
 
@@ -187,6 +188,7 @@ class TopKEngine:
                 "fused_pivot_chunks": 0,  # cursors through pivot_score (§13)
                 "theta_device_rounds": 0,  # device-carried theta rounds
                 "device_round_trips": 0,  # blocking device->host fetches
+                "membership_pairs": 0,  # (term, candidate) pairs searched
             },
             engine="topk",
         )
@@ -1324,6 +1326,47 @@ class TopKEngine:
     # ------------------------------------------------------------------
     # batched bound-filter + exact scoring of per-query candidate sets
     # ------------------------------------------------------------------
+    def _membership(self, specs, need_ub: bool):
+        """The membership pass of ``_score_specs`` over the flat lane mirror.
+
+        A key ``doc + t * stride`` can only land in term t's own lane,
+        ``flat_keys[lane_end[t] : lane_end[t + 1]]``, so each (query, term)
+        searches its candidates there and never the whole mirror.  Pairs
+        are laid out query-major, term-major, doc order.  Returns (pos: the
+        flat slot of every pair, cuts: each query's first pair, mems: per
+        query the [T, D] member mask, ubs: per query the doc-aligned
+        block-max upper bound -- None without ``need_ub``).  The UB sums
+        term by term from 0.0, which keeps it bit-identical to a row-wise
+        ``sum(axis=0)`` of the [T, D] table.
+        """
+        a, core = self.arena, self.core
+        cuts = [0]
+        for terms, _, docs in specs:
+            cuts.append(cuts[-1] + len(terms) * len(docs))
+        self.stats["membership_pairs"] += cuts[-1]
+        last_slot = a.n_blocks * BLOCK_VALS - 1
+        pos = np.empty(cuts[-1], np.int64)
+        mems, ubs = [], []
+        for i, (terms, mult, docs) in enumerate(specs):
+            T, D = len(terms), len(docs)
+            pos_i = pos[cuts[i] : cuts[i + 1]].reshape(T, D)
+            mem = np.zeros((T, D), bool)
+            ub = np.zeros(D, np.float64) if need_ub else None
+            for j, t in enumerate(terms):
+                lo, hi = core.lane_end[t], core.lane_end[t + 1]
+                p = lo + np.searchsorted(
+                    core.flat_keys[lo:hi], docs + t * a.stride, "left"
+                )
+                pos_i[j] = p
+                mem[j] = (core.flat_vals[p] == docs) & (p < hi)
+                if need_ub:
+                    ub += mult[j] * np.where(
+                        mem[j], self.bounds[np.minimum(p, last_slot) >> 7], 0.0
+                    )
+            mems.append(mem)
+            ubs.append(ub)
+        return pos, cuts, mems, ubs
+
     def _score_specs(
         self,
         specs: list[tuple[np.ndarray, np.ndarray, np.ndarray]],
@@ -1337,8 +1380,9 @@ class TopKEngine:
         tested in tests/test_pivot_kernel.py).
 
         One membership pass over the flat lane mirror resolves EVERY
-        (term, doc) pair of the batch at once (a single searchsorted; no
-        decode, no scoring).  It yields, per pair, membership and the
+        (term, doc) pair of the batch (``_membership``: per query term, one
+        searchsorted of the candidates in the term's own lane; no decode,
+        no scoring).  It yields, per pair, membership and the
         owning arena block, from which the Block-Max WAND pivot test runs
         doc-aligned: UB(doc) = sum over member pairs of mult * block bound
         >= score(doc).  Only MEMBER pairs of surviving docs are ever scored
@@ -1354,50 +1398,15 @@ class TopKEngine:
         provably outside the top-k (score <= UB < theta <= final k-th).
         """
         self._flat_init()
-        a, core = self.arena, self.core
+        core = self.core
         nq = len(specs)
         with obs.span("membership", path="ranked"):
-            t_chunks, d_chunks, cuts = [], [], [0]
-            for terms, _, docs in specs:
-                t_chunks.append(np.repeat(terms, len(docs)))
-                d_chunks.append(np.tile(docs, len(terms)))
-                cuts.append(cuts[-1] + len(terms) * len(docs))
+            pos, cuts, mems, ubs = self._membership(specs, theta is not None)
             if cuts[-1] == 0:
                 return [
                     (np.zeros(0, np.int64), np.zeros(0, np.float64))
                     for _ in specs
                 ], (None if theta is None else theta.copy())
-            t_rep = np.concatenate(t_chunks)
-            d_til = np.concatenate(d_chunks)
-            pos = np.searchsorted(
-                core.flat_keys, d_til + t_rep * a.stride, "left"
-            )
-            past = pos >= core.lane_end[t_rep + 1]
-            member = (core.flat_vals[pos] == d_til) & ~past
-            row = np.minimum(pos, a.n_blocks * BLOCK_VALS - 1) >> 7
-
-            need_ub = theta is not None
-            mems, ubs = [], []
-            for i, (terms, mult, docs) in enumerate(specs):
-                T, D = len(terms), len(docs)
-                if T == 0 or D == 0:
-                    mems.append(np.zeros((T, D), bool))
-                    ubs.append(np.zeros(D, np.float64))
-                    continue
-                sl = slice(cuts[i], cuts[i + 1])
-                mem = member[sl].reshape(T, D)
-                mems.append(mem)
-                if need_ub:
-                    ubs.append(
-                        (
-                            mult[:, None]
-                            * np.where(
-                                mem, self.bounds[row[sl].reshape(T, D)], 0.0
-                            )
-                        ).sum(axis=0)
-                    )
-                else:
-                    ubs.append(None)
 
         def pairs_for(sels: list[np.ndarray]):
             """Member-pair segments of the selected doc slots: per query

@@ -426,3 +426,26 @@ def test_intersect_counts_batches_and_device_round_trips(ranked_index,
     eng_np.intersect_batch(queries)
     assert eng_np.stats["batches"] == 1
     assert eng_np.stats["device_round_trips"] == 0
+
+
+@pytest.mark.parametrize("resident", ["mirror", "kernel"])
+def test_topk_counts_membership_pairs(ranked_index, monkeypatch, resident):
+    """``stats["membership_pairs"]`` adds T x D per query of every
+    membership pass (seed and rescore), mirrored to the armed registry."""
+    from repro.ranked.topk_engine import TopKEngine
+
+    idx, queries = ranked_index
+    eng = TopKEngine(idx, backend="numpy", seed_blocks=2, resident=resident)
+    passes = []
+    real = TopKEngine._score_specs
+
+    def spy(self, specs, *a, **k):
+        passes.append(sum(len(t) * len(d) for t, _, d in specs))
+        return real(self, specs, *a, **k)
+
+    monkeypatch.setattr(TopKEngine, "_score_specs", spy)
+    eng.topk_batch(queries, 10)
+    assert len(passes) >= 2  # the seed pass and at least one rescore
+    assert eng.stats["membership_pairs"] == sum(passes) > 0
+    counter = obs.counter("ranked_membership_pairs", engine="topk")
+    assert counter.value == sum(passes)

@@ -21,9 +21,15 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core.index import build_partitioned_index
-from repro.data.postings import make_queries, make_ranked_corpus
+from repro.core.index import (
+    TAG_BITVECTOR,
+    TAG_EF,
+    TAG_VBYTE,
+    build_partitioned_index,
+)
+from repro.data.postings import make_freqs, make_queries, make_ranked_corpus
 from repro.kernels.bm25_score.ops import bm25_score_probe, bm25_score_rows
+from repro.kernels.vbyte_decode.kernel import BLOCK_VALS
 from repro.ranked.bm25 import (
     DEFAULT_BM25,
     dequant_norm,
@@ -298,3 +304,127 @@ def test_uniform_strategy_also_ranked():
         for (gd, gs), (wd, ws) in zip(got, want):
             assert np.array_equal(gd, wd), strategy
             assert np.array_equal(gs, ws), strategy
+
+
+# ---------------------------------------------------------------------------
+# membership pass: per-lane search == one global search of the flat mirror
+# ---------------------------------------------------------------------------
+
+def _global_membership(eng, specs):
+    """Reference: every (term, doc) pair of the batch materialised, ONE
+    searchsorted over the whole flat mirror, masked by the next lane's
+    start.  Per query: (member [T, D], pos [T, D], UB [D])."""
+    a, core = eng.arena, eng.core
+    out = []
+    for terms, mult, docs in specs:
+        T, D = len(terms), len(docs)
+        t_rep, d_til = np.repeat(terms, D), np.tile(docs, T)
+        pos = np.searchsorted(core.flat_keys, d_til + t_rep * a.stride, "left")
+        member = (core.flat_vals[pos] == d_til) & (
+            pos < core.lane_end[t_rep + 1]
+        )
+        row = np.minimum(pos, a.n_blocks * BLOCK_VALS - 1) >> 7
+        mem, pos = member.reshape(T, D), pos.reshape(T, D)
+        ub = (
+            mult[:, None]
+            * np.where(mem, eng.bounds[row.reshape(T, D)], 0.0)
+        ).sum(axis=0)
+        out.append((mem, pos, ub))
+    return out
+
+
+@pytest.fixture(scope="module")
+def multicodec_ranked():
+    """Bitvector (gap-1 run), EF (clustered), VByte (sparse) partitions, a
+    list mixing all three, and a one-posting list; lengths that are not
+    multiples of 128 leave padding lanes at every list's end."""
+    rng = np.random.default_rng(7)
+    dense = np.arange(5_000, 9_000, dtype=np.int64)
+    clustered = 20_000 + np.cumsum(
+        rng.choice([1, 2, 6, 10, 20, 30], size=1_500)
+    ).astype(np.int64)
+    sparse = 100 + np.cumsum(rng.integers(65, 128, size=700)).astype(np.int64)
+    mixed = np.unique(np.concatenate([dense[::3], clustered[::2], sparse[::4]]))
+    lists = [dense, clustered, sparse, mixed, np.array([7_777], np.int64)]
+    idx = build_partitioned_index(
+        lists, "optimal", freqs=make_freqs(rng, lists), codecs="auto"
+    )
+    tags = set(np.asarray(idx.tags).tolist())
+    assert {TAG_BITVECTOR, TAG_EF, TAG_VBYTE} <= tags
+    return idx, lists
+
+
+def _spec(terms, docs, mult=None):
+    terms = np.asarray(terms, np.int64)
+    mult = np.ones(len(terms)) if mult is None else np.asarray(mult, np.float64)
+    return terms, mult, np.unique(np.asarray(docs, np.int64))
+
+
+def _membership_cases(lists):
+    dense, clustered, sparse, mixed, one = lists
+    rng = np.random.default_rng(11)
+    edges = [
+        0, dense[0] - 1, dense[-1] + 1, clustered[0] - 1, clustered[-1] + 1,
+        sparse[0] - 1, sparse[-1] + 1, mixed[0] - 1, mixed[-1] + 1,
+    ]
+    between = [sparse[10] + 1, sparse[300] - 1, clustered[40] + 1, mixed[7] + 1]
+    hits = [dense[0], dense[-1], clustered[0], clustered[-1], sparse[0],
+            sparse[-1], mixed[0], mixed[-1]]
+    union = np.concatenate(lists)
+    return {
+        "one_posting": [
+            _spec([4], [one[0] - 1, one[0], one[0] + 1]),
+            _spec([3, 4], [one[0], mixed[0], mixed[100], 12_345], [2, 1]),
+        ],
+        "outside_and_between": [
+            _spec([0, 1, 2, 3], edges + between + hits, [1, 2, 1, 3]),
+            _spec([2], between + [sparse[-1] + 10_000]),
+        ],
+        "term_without_candidates": [
+            _spec([0, 2, 4], clustered[::50]),  # no candidate in 0, 2, 4
+            _spec([1, 3], []),  # no candidates at all
+            _spec([1], clustered[:5]),
+        ],
+        "mixed_batch": [
+            _spec(
+                np.unique(rng.choice(5, size=int(n), replace=False)),
+                np.concatenate([
+                    rng.choice(union, size=200),
+                    rng.integers(0, int(union.max()) + 500, size=200),
+                ]),
+            )
+            for n in rng.integers(1, 5, size=6)
+        ],
+    }
+
+
+@pytest.mark.parametrize("resident", ["mirror", "kernel"])
+@pytest.mark.parametrize(
+    "case",
+    ["one_posting", "outside_and_between", "term_without_candidates",
+     "mixed_batch"],
+)
+def test_membership_matches_global_search(multicodec_ranked, case, resident):
+    """Searching each term's own lane gives the global search's member
+    mask, member positions and block-max UBs, bit for bit."""
+    idx, lists = multicodec_ranked
+    eng = TopKEngine(idx, backend="numpy", resident=resident,
+                     codec_policy="auto")
+    eng._flat_init()
+    specs = _membership_cases(lists)[case]
+    pos, cuts, mems, ubs = eng._membership(specs, need_ub=True)
+    _, _, mems_n, ubs_n = eng._membership(specs, need_ub=False)
+    want = _global_membership(eng, specs)
+    assert cuts[-1] == len(pos) == sum(len(t) * len(d) for t, _, d in specs)
+    assert eng.stats["membership_pairs"] == 2 * cuts[-1]
+    n_members = 0
+    for i, (w_mem, w_pos, w_ub) in enumerate(want):
+        T, D = w_mem.shape
+        got_pos = pos[cuts[i] : cuts[i + 1]].reshape(T, D)
+        assert np.array_equal(mems[i], w_mem), (case, i)
+        assert np.array_equal(mems_n[i], w_mem), (case, i)
+        assert np.array_equal(got_pos[w_mem], w_pos[w_mem]), (case, i)
+        assert ubs[i].dtype == np.float64 and ubs_n[i] is None
+        assert np.array_equal(ubs[i].view(np.int64), w_ub.view(np.int64))
+        n_members += int(w_mem.sum())
+    assert n_members > 0
