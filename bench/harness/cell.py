@@ -16,14 +16,23 @@ After the window the device's peak memory is read, the system's state is
 dropped, and a sample of the answers (drawn from the seed, with the
 heaviest query in it) is compared with the plain reference.
 
+A configuration with a ``deployment`` block (see ``spec``) builds both
+engines sharded over that many chips, through the same entry points.  A
+traffic with ``faults`` serves through a ``ResilientEngine`` over an arena
+checkpoint written in set-up; warm-up kills and restores each fault's
+shard once, the window kills it at its ``at_s``, and the check adds the
+failover answers, the degraded ones and the shards left unrecovered.
+
 Streams of the run's seed: 0 list-id order, 1 warm-up, 2 window, 3 check
-sample (the corpus's contents and the query sets come from the
-configuration's and the traffic's own seeds; see ``corpus`` and ``traffic``).
+sample, 4 failover check sample (the corpus's contents and the query sets
+come from the configuration's and the traffic's own seeds; see ``corpus``
+and ``traffic``).
 """
 
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import gc
 import math
 import shutil
@@ -117,6 +126,8 @@ class Run:
     counters: dict = field(default_factory=dict)  # repro.obs counters over it
     bits_per_int: float = float("nan")
     trace: trace_mod.TraceSummary = None
+    # traffic with faults: see ``window_faults``
+    failover: dict = None
 
 
 @dataclass
@@ -130,6 +141,7 @@ class Setup:
     engine: object
     postings: int
     phases: dict
+    resilient: object = None  # the ResilientEngine, where the traffic kills shards
 
 
 def check_device(chips: int):
@@ -158,6 +170,140 @@ def require_compiled(engine, require_chip: bool) -> None:
         raise BenchFailure(f"the engine runs backend/interpret/resident = {mode}")
 
 
+def require_mesh(engine, layout, require_chip: bool) -> None:
+    """A chip run of a sharded layout serves its shards from one mesh, one
+    chip to a shard, or raises BenchFailure."""
+    if layout is None or not require_chip:
+        return
+    mesh = engine.sharded.mesh
+    axis = None if mesh is None else dict(mesh.shape).get("shard")
+    if axis != layout["shards"]:
+        raise BenchFailure(
+            f"the configuration lays the index out in {layout['shards']} "
+            f"shards, one to a chip, but the engine put them on no mesh with a "
+            f"'shard' axis of {layout['shards']} devices (mesh: {mesh}): the "
+            "shards would run as a host loop on one chip")
+
+
+def make_resilient(s: Setup, directory: str) -> dict:
+    """Wrap the engine in a ``ResilientEngine`` whose injector kills the
+    traffic's shards in order, and write the arena checkpoint that
+    recovery restores from into ``directory``."""
+    from repro.checkpoint import CheckpointManager
+    from repro.distributed.resilient import ResilientEngine, ShardFaultInjector
+
+    t0 = time.perf_counter()
+    s.resilient = ResilientEngine(
+        s.engine,
+        injector=ShardFaultInjector(shards=[f["shard"] for f in s.cell.faults]),
+        manager=CheckpointManager(directory, keep=1, async_save=False),
+        recover_async=True,
+    )
+    s.resilient.checkpoint()
+    return {"checkpoint_s": time.perf_counter() - t0}
+
+
+class Waves:
+    """The harness's view of a ``ResilientEngine``: each call's results go
+    on to the caller, and its ``ServeInfo`` is kept with the wave's start
+    and end in seconds from ``t0`` and the shards routed around after it.
+
+    ``at_s``: when, from ``t0``, the loop driving the waves calls ``arm``,
+    which kills the injector's next shard at the next wave.  ``keep(i,
+    query, answer)`` sees every answer of a wave served with a shard
+    failed or dead, numbered from 0.  The engine's counters as they stand
+    at construction are the baseline of ``window_faults``."""
+
+    def __init__(self, resilient, at_s=(), keep=None, clock=time.perf_counter):
+        self.res, self.at_s, self.keep, self.clock = (
+            resilient, list(at_s), keep, clock)
+        self.t0 = clock()
+        self.records: list = []
+        self.armed: list = []
+        self.kept = 0
+        self.fired0 = resilient.injector.fired
+        self.stats0 = dict(resilient.stats)
+        self.recovered0 = len(resilient.stats["recovery_s"])
+
+    def arm(self) -> None:
+        inj = self.res.injector
+        inj.at_batches.add(inj.batch + 1)
+        self.armed.append(self.clock() - self.t0)
+
+    def _call(self, fn, queries, *args):
+        t0 = self.clock() - self.t0
+        results, info = fn(queries, *args)
+        rec = {"t0": t0, "t1": self.clock() - self.t0,
+               "failed_shards": [int(x) for x in info.failed_shards],
+               "retries": int(info.retries), "degraded": bool(info.degraded),
+               "dead_shards": [int(x) for x in np.flatnonzero(self.res.sa.dead)],
+               "answers": sum(1 for q in queries if len(q))}
+        self.records.append(rec)
+        if self.keep is not None and (rec["failed_shards"] or rec["dead_shards"]):
+            for q, a in zip(queries, results):
+                if len(q):
+                    self.keep(self.kept, q, a)
+                    self.kept += 1
+        return results
+
+    def topk_batch(self, queries, k):
+        return self._call(self.res.topk_batch, queries, k)
+
+    def intersect_batch(self, queries):
+        return self._call(self.res.intersect_batch, queries)
+
+
+def _secs(xs) -> str:
+    return ",".join(str(round(x, 3)) for x in xs) or "-"
+
+
+def window_faults(waves: Waves, cell: spec.Cell) -> dict:
+    """What the window's faults did, once its answers are in and the
+    restores have had ``GRACE_S`` to finish: faults scheduled and fired,
+    every wave's record, each kill with the start of the wave that found
+    the shard dead and of the first wave served after it was re-admitted
+    (seconds from the window's start), the engine's counters over the
+    window, each restore's seconds from death to re-admission, the answers
+    of degraded waves, the shards left unhealthy, and the engine's health.
+    Raises BenchFailure where fewer faults fired than were scheduled."""
+    from repro.distributed.resilient import HEALTHY
+
+    res = waves.res
+    res.wait_recovered(GRACE_S)
+    scheduled, fired = len(cell.faults), res.injector.fired - waves.fired0
+    kills, down = [], set()
+    for w in waves.records:
+        now = set(w["dead_shards"])
+        kills += [{"shard": sh, "killed_at": w["t0"], "readmitted_at": None}
+                  for sh in sorted(now - down)]
+        for k in kills:
+            if k["readmitted_at"] is None and k["shard"] in down - now:
+                k["readmitted_at"] = w["t0"]
+        down = now
+    stats = {key: res.stats[key] - waves.stats0[key] for key in
+             ("failovers", "retries", "degraded_batches", "dead_events",
+              "recoveries")}
+    out = dict(scheduled=scheduled, fired=fired, armed_at=list(waves.armed),
+               waves=waves.records, kills=kills, **stats,
+               recovery_s=list(res.stats["recovery_s"][waves.recovered0:]),
+               degraded_answers=sum(w["answers"] for w in waves.records
+                                    if w["degraded"]),
+               unrecovered_shards=sum(h != HEALTHY for h in res.health),
+               health=res.health_summary())
+    say("failover", scheduled=scheduled, fired=fired, armed_at=_secs(waves.armed),
+        killed_at=_secs(k["killed_at"] for k in kills),
+        readmitted_at=_secs(k["readmitted_at"] for k in kills
+                            if k["readmitted_at"] is not None),
+        failover_waves=sum(1 for w in waves.records
+                           if w["failed_shards"] or w["dead_shards"]),
+        **stats, degraded_answers=out["degraded_answers"],
+        recovery_s=_secs(out["recovery_s"]), health=",".join(res.health))
+    if fired < scheduled:
+        raise BenchFailure(f"{fired} of the traffic's {scheduled} faults fired "
+                           "in the window: the run measured no failover")
+    return out
+
+
 def device_bytes() -> int:
     """Bytes of every array the process holds on its devices."""
     import jax
@@ -184,7 +330,7 @@ def setup(cell: spec.Cell, seed: int, log: CompileLog, require_chip: bool) -> Se
     """Generate, build, upload, make the engine; warm-up is separate."""
     import jax
 
-    from repro.api import make_query_engine, make_topk_engine
+    from repro.api import EngineConfig, make_query_engine, make_topk_engine
     from repro.core import build_partitioned_index
 
     cfg, tr = cell.config, cell.traffic
@@ -210,13 +356,18 @@ def setup(cell: spec.Cell, seed: int, log: CompileLog, require_chip: bool) -> Se
 
     t0 = time.perf_counter()
     before = log.snapshot()
+    config = None if cell.layout is None else EngineConfig(
+        shards=cell.layout["shards"], replicas=cell.layout["replicas"],
+        codec_policy=codecs)
     if tr["operation"] == "topk":
-        engine = make_topk_engine(index)
+        engine = make_topk_engine(index, config)
         require_compiled(engine, require_chip)
+        require_mesh(engine, cell.layout, require_chip)
         engine.topk_batch([[int(rank_to_list[-1])]], int(tr["k"]))
     elif tr["operation"] == "and":
-        engine = make_query_engine(index)
+        engine = make_query_engine(index, config)
         require_compiled(engine, require_chip)
+        require_mesh(engine, cell.layout, require_chip)
         engine.intersect_batch([[int(rank_to_list[-1]), int(rank_to_list[-2])]])
     else:
         raise spec.SpecError(f"unknown operation {tr['operation']!r}")
@@ -238,10 +389,13 @@ def warm_up(s: Setup, log: CompileLog) -> dict:
     a closed loop) until a round loads no new program, then, for an open
     loop, segments of open-loop traffic at the cell's rate until one loads
     no new program.  The engine's shapes follow the data, so a segment
-    forms the window's own kinds of waves; it also fills the score cache."""
+    forms the window's own kinds of waves; it also fills the score cache.
+    Where the traffic kills shards, each kill is then rehearsed once
+    (``rehearse_faults``)."""
     import jax
 
     tr = s.cell.traffic
+    front = s.engine if s.resilient is None else Waves(s.resilient)
     wu = tr["warmup"]
     op, k = tr["operation"], int(tr.get("k", 0))
     rng = rng_stream(s.seed, 1)
@@ -262,7 +416,7 @@ def warm_up(s: Setup, log: CompileLog) -> dict:
         for b in sizes:
             t1 = time.perf_counter()
             with jax.profiler.TraceAnnotation("bench.warmup"):
-                serve(s.engine, log_q[pos: pos + b], k, op)
+                serve(front, log_q[pos: pos + b], k, op)
             wave_s.append(f"{b}:{time.perf_counter() - t1:.3f}")
             pos += b
         rounds += 1
@@ -279,8 +433,10 @@ def warm_up(s: Setup, log: CompileLog) -> dict:
                                             100 + segments)
             due = traffic_mod.poisson_due(rng, rate, n)
             before = log.loaded()
+            waves = None if s.resilient is None else Waves(s.resilient)
             with jax.profiler.TraceAnnotation("bench.warmup"):
-                _, answer, _, _, t_close = open_loop(s, queries, due, seg_s)
+                _, answer, _, _, t_close = open_loop(s, queries, due, seg_s,
+                                                     waves=waves)
             segments += 1
             pos += n
             lat = np.where(np.isfinite(answer), answer - due, t_close - due)
@@ -288,8 +444,50 @@ def warm_up(s: Setup, log: CompileLog) -> dict:
                 p95_ms=round(nearest_rank(lat, 0.95) * 1e3, 3))
             if segments >= int(wu["min_segments"]) and log.loaded() == before:
                 break
-    return dict(warmup_s=time.perf_counter() - t0, warmup_rounds=rounds,
-                warmup_segments=segments, warmup_queries=pos)
+    out = dict(warmup_s=time.perf_counter() - t0, warmup_rounds=rounds,
+               warmup_segments=segments, warmup_queries=pos)
+    if s.resilient is not None:
+        out.update(rehearse_faults(s, log, sizes, rng))
+        out["warmup_s"] = time.perf_counter() - t0
+    return out
+
+
+def rehearse_faults(s: Setup, log: CompileLog, sizes: list, rng) -> dict:
+    """Each of the traffic's kills once, before the window: the shard dies
+    at the first wave of a round of every wave size, the round fails over,
+    the restore is awaited, and a second round re-admits the shard at its
+    first wave.  The failover waves' programs load here, not in the window,
+    and the injector's victims come round to the first again."""
+    import jax
+
+    from repro.distributed.resilient import HEALTHY
+
+    tr, res = s.cell.traffic, s.resilient
+    op, k = tr["operation"], int(tr.get("k", 0))
+    t0 = time.perf_counter()
+    for i, f in enumerate(s.cell.faults):
+        log_q = traffic_mod.query_log(rng, tr, s.rank_to_list, 2 * sum(sizes),
+                                      200 + i)
+        waves = Waves(res)
+        before = log.loaded()
+        waves.arm()
+        pos = 0
+        for round_ in range(2):
+            if round_:
+                res.wait_recovered(GRACE_S)
+            for b in sizes:
+                with jax.profiler.TraceAnnotation("bench.warmup"):
+                    serve(waves, log_q[pos: pos + b], k, op)
+                pos += b
+        failover = sum(1 for w in waves.records
+                       if w["failed_shards"] or w["dead_shards"])
+        say("warmup", fault=i + 1, shard=f["shard"], loaded=log.loaded() - before,
+            failover_waves=failover, health=",".join(res.health))
+        if failover == 0 or any(h != HEALTHY for h in res.health):
+            raise BenchFailure(
+                f"rehearsing the kill of shard {f['shard']}: {failover} waves "
+                f"failed over, health after the restore {res.health}")
+    return {"fault_rehearsal_s": time.perf_counter() - t0}
 
 
 class _Annotated:
@@ -305,10 +503,13 @@ class _Annotated:
             return self._engine.topk_batch(queries, k)
 
 
-def open_loop(s: Setup, queries, due, seconds: float, clock=time.perf_counter):
+def open_loop(s: Setup, queries, due, seconds: float, clock=time.perf_counter,
+              waves: Waves | None = None):
     """Drive ``AsyncTopKServer`` with requests due at ``due`` (s from the
     window's start).  Returns (admit, answer, wait_s, results, t_close):
-    times in seconds from the window's start, NaN where never answered."""
+    times in seconds from the window's start, NaN where never answered.
+    With ``waves`` the server serves through it, and the loop arms its
+    kills at their times."""
     import jax
 
     from repro.serving import AsyncTopKServer
@@ -325,7 +526,8 @@ def open_loop(s: Setup, queries, due, seconds: float, clock=time.perf_counter):
         # the queue holds every request: an overloaded window builds a
         # backlog that drains after the close, and sheds nothing
         server = AsyncTopKServer(
-            _Annotated(s.engine), k=int(tr["k"]), max_batch=int(tr["batch"]),
+            _Annotated(s.engine if waves is None else waves), k=int(tr["k"]),
+            max_batch=int(tr["batch"]),
             max_queue=max(n, 1), max_delay_s=float(tr["linger_ms"]) * 1e-3,
         )
         take = server.former.take
@@ -337,6 +539,12 @@ def open_loop(s: Setup, queries, due, seconds: float, clock=time.perf_counter):
         server.former.take = take_annotated
         async with server:
             t0 = clock()
+            timers = []
+            if waves is not None:
+                waves.t0 = t0
+                loop = asyncio.get_running_loop()
+                timers = [loop.call_later(max(t0 + at - clock(), 0.0), waves.arm)
+                          for at in waves.at_s]
 
             async def one(i):
                 admit[i] = clock() - t0
@@ -357,7 +565,7 @@ def open_loop(s: Setup, queries, due, seconds: float, clock=time.perf_counter):
                 tasks.append(asyncio.ensure_future(one(i)))
             rest = max(t0 + seconds + GRACE_S - clock(), 0.0)
             done, pending = await asyncio.wait(tasks, timeout=rest)
-            for t in pending:
+            for t in [*pending, *timers]:
                 t.cancel()
             for t in done:
                 t.result()  # a wave that raised fails the run
@@ -367,20 +575,29 @@ def open_loop(s: Setup, queries, due, seconds: float, clock=time.perf_counter):
     return admit, answer, wait_s, results, t_close
 
 
-def closed_loop(s: Setup, log_q, seconds: float, keep, clock=time.perf_counter):
+def closed_loop(s: Setup, log_q, seconds: float, keep, clock=time.perf_counter,
+                waves: Waves | None = None):
     """Replay the whole of ``log_q`` in batches, again and again, until a
     replay ends at or after ``seconds``: every window does whole replays, so
     every seed's window does the same work.  ``keep(i, answer)`` sees every
-    answer.  Returns (queries served, window seconds)."""
+    answer.  With ``waves`` the batches go through it, and its kills are
+    armed between batches.  Returns (queries served, window seconds)."""
     import jax
 
     b = int(s.cell.traffic["batch"])
+    engine = s.engine if waves is None else waves
+    kills = [] if waves is None else list(waves.at_s)
     served = 0
     t0 = clock()
+    if waves is not None:
+        waves.t0 = t0
     while served == 0 or clock() - t0 < seconds:
         for start in range(0, len(log_q), b):
+            while kills and clock() - t0 >= kills[0]:
+                kills.pop(0)
+                waves.arm()
             with jax.profiler.TraceAnnotation("bench.intersect_batch"):
-                out = s.engine.intersect_batch(log_q[start: start + b])
+                out = engine.intersect_batch(log_q[start: start + b])
             for j, a in enumerate(out):
                 keep(served + j, a)
             served += len(out)
@@ -438,12 +655,15 @@ def run(root: Path, workload: str, seed: int, seconds: float, traced: bool,
 
     armed = obs.enabled()
     try:
-        return _run(root, workload, seed, seconds, traced, t_start, require_chip)
+        with contextlib.ExitStack() as scratch:
+            return _run(root, workload, seed, seconds, traced, t_start,
+                        require_chip, scratch)
     finally:
         obs.enable(armed)
 
 
-def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
+def _run(root, workload, seed, seconds, traced, t_start, require_chip,
+         scratch: contextlib.ExitStack) -> dict:
     import jax
 
     from repro import obs
@@ -462,6 +682,9 @@ def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
         obs.enable()  # the engines count score-cache hits only when armed
     s = setup(cell, seed, log, require_chip)
     phases = dict(s.phases)
+    if cell.faults:
+        phases.update(make_resilient(s, scratch.enter_context(
+            tempfile.TemporaryDirectory(prefix="bench-ckpt-"))))
     phases.update(warm_up(s, log))
     bits = 8.0 * device_bytes() / s.postings
     setup_s = time.perf_counter() - t_start
@@ -489,12 +712,19 @@ def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
     lists, freqs = s.lists, s.freqs
     res = Reservoir(rng_stream(seed, 3), int(tr["check_sample"]),
                     lambda q: sum(len(lists[t]) for t in q))
+    waves = failover_sample = None
+    if s.resilient is not None:
+        failover_sample = Reservoir(rng_stream(seed, 4),
+                                    int(tr["check_sample"]) // 4, res.weight)
+        waves = Waves(s.resilient, keep=failover_sample,
+                      at_s=[f["at_s"] for f in cell.faults if f["at_s"] < seconds])
     if tr["loop"] == "open":
         n = max(int(round(float(tr["rate_qps"]) * seconds)), 1)
         queries = traffic_mod.query_log(rw, tr, s.rank_to_list, n, 2)
         due = traffic_mod.poisson_due(rw, float(tr["rate_qps"]), n)
         with jax.profiler.TraceAnnotation(WINDOW_SPAN):
-            admit, answer, wait_s, results, t_close = open_loop(s, queries, due, seconds)
+            admit, answer, wait_s, results, t_close = open_loop(
+                s, queries, due, seconds, waves=waves)
         answered = np.isfinite(answer)
         latency = np.where(answered, answer - due, t_close - due)
         record.due, record.admit, record.wait_s = due, admit, wait_s
@@ -519,7 +749,7 @@ def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
         with jax.profiler.TraceAnnotation(WINDOW_SPAN):
             served, window_s = closed_loop(
                 s, log_q, seconds,
-                lambda i, a: res(i, log_q[i % len(log_q)], a))
+                lambda i, a: res(i, log_q[i % len(log_q)], a), waves=waves)
         record.n_queries = attempted = served
         failed = 0
         e2e = {"qps": served / window_s}
@@ -530,6 +760,8 @@ def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
     say("compiles_in_window", loaded=log.loaded() - loaded0,
         from_cache=log.hits - hits0,
         compiled=(log.compiles - compiles0) - (log.hits - hits0))
+    if waves is not None:
+        record.failover = window_faults(waves, cell)
     e2e["device_bits_per_posting"] = bits
     e2e["build_mpostings_s"] = s.postings / 1e6 / (
         phases["build_s"] + phases["upload_s"])
@@ -542,19 +774,26 @@ def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
 
     mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs]
     mem = [m for m in mem if m is not None]
+    if len(mem) > 1:
+        say("memory", peak_bytes_per_chip=",".join(str(m) for m in mem))
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": max(mem) if mem else 0}
     sample = res.sample()
-    del s  # drop the system's state before the reference runs
+    failover = None if waves is None else dict(
+        sample=failover_sample.sample(), **record.failover)
+    del s, waves  # drop the system's state before the reference runs
     gc.collect()
 
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.check"):
-        checks = check(cell, lists, freqs, sample, k, failed)
-    say("check", sampled=len(sample), check_s=round(time.perf_counter() - t0, 3))
+        checks = check(cell, lists, freqs, sample, k, failed, failover)
+    extra = {} if failover is None else {"failover_sampled": len(failover["sample"])}
+    say("check", sampled=len(sample), **extra,
+        check_s=round(time.perf_counter() - t0, 3))
 
     out = {"correct": all(c["value"] <= c["limit"] for c in checks.values())
-           and len(sample) > 0,
+           and len(sample) > 0
+           and (failover is None or len(failover["sample"]) > 0),
            "attempted": int(attempted), "failed": int(failed)}
     if traced:
         devices, host = trace_mod.load(trace_dir)
@@ -581,22 +820,35 @@ def _run(root, workload, seed, seconds, traced, t_start, require_chip) -> dict:
     return out
 
 
-def check(cell: spec.Cell, lists, freqs, sample: dict, k: int, failed: int) -> dict:
-    """The numbers ``correct`` is decided on, each beside its limit."""
-    op = cell.traffic["operation"]
-    wrong = 0
-    if op == "topk":
+def check(cell: spec.Cell, lists, freqs, sample: dict, k: int, failed: int,
+          failover: dict | None = None) -> dict:
+    """The numbers ``correct`` is decided on, each beside its limit.  Where
+    the traffic kills shards, ``failover`` is the window's failover report
+    with a ``sample`` of the answers of waves served with a shard failed
+    or dead; those answers, the degraded ones and the shards left
+    unrecovered are compared too."""
+    if cell.traffic["operation"] == "topk":
         bm = cell.config["scoring"]
         ref = reference.BM25(lists, freqs, k1=bm["k1"], b=bm["b"],
                              levels=bm["norm_levels"])
-        for q, got in sample.values():
-            if not reference.same_topk(got, ref.topk(q, k)):
-                wrong += 1
+
+        def is_wrong(q, got):
+            return not reference.same_topk(got, ref.topk(q, k))
     else:
-        for q, got in sample.values():
-            if not reference.same_and(got, reference.intersect(lists, q)):
-                wrong += 1
-    return {
+        def is_wrong(q, got):
+            return not reference.same_and(got, reference.intersect(lists, q))
+
+    wrong = sum(is_wrong(q, got) for q, got in sample.values())
+    out = {
         "wrong_answers": {"value": wrong, "limit": 0},
         "unanswered": {"value": int(failed), "limit": 0},
     }
+    if failover is not None:
+        out["failover_wrong_answers"] = {
+            "value": sum(is_wrong(q, got) for q, got in failover["sample"].values()),
+            "limit": 0}
+        out["degraded_answers"] = {"value": failover["degraded_answers"],
+                                   "limit": 0}
+        out["unrecovered_shards"] = {"value": failover["unrecovered_shards"],
+                                     "limit": 0}
+    return out
