@@ -55,6 +55,9 @@ def pack_sorted_int_array(arr: np.ndarray) -> dict:
 
 
 def unpack_sorted_int_array(packed: dict) -> np.ndarray:
+    """Decode a packed array on the host, partition by partition, into
+    int64.  No device arena: its docID lanes are int32, and leaves such as
+    an arena's ``block_keys`` (list x stride + docID) pass 2^31."""
     idx = PartitionedIndex(
         n_lists=1,
         list_part_offsets=packed["list_part_offsets"],
@@ -65,7 +68,11 @@ def unpack_sorted_int_array(packed: dict) -> np.ndarray:
         offsets=packed["offsets"],
         payload=packed["payload"],
     )
-    return idx.decode_list(0)
+    chunks, base = [], -1
+    for p in range(len(idx.endpoints)):
+        chunks.append(idx._decode_partition(p, base))
+        base = int(idx.endpoints[p])
+    return np.concatenate(chunks) if chunks else np.zeros(0, np.int64)
 
 
 def _is_strictly_increasing(a: np.ndarray) -> bool:

@@ -26,13 +26,18 @@ smaller arena with the same invariants:
   host-side scatter at the result boundary.
 * **placement**: with a ``jax.sharding.Mesh`` over a "shard" axis (one
   device per shard), the per-shard tiles, sidecars, and ranked freq blocks
-  are stacked [S, ...] (padded to the largest shard) and placed with
+  are stacked [S, ...] (padded to extents of the arena alone, ``_pads``)
+  and placed with
   ``NamedSharding(mesh, P("shard"))`` -- each device holds ONLY its shard.
   Queries then run as ONE ``shard_map`` dispatch: every device executes the
   same fused locate -> decode_search (or bm25 locate -> decode+score+match)
   program over its resident shard.  Without a mesh (or on the numpy
   backend) the shards are served as a host-side loop over per-shard
   ``EngineCore``s -- same results, same routing, no device collective.
+  Each stacked array is assembled from one buffer per device, so a lost
+  shard's buffers are deleted on its own device (``evict``): the
+  dispatchers then run over the live shards' sub-mesh until the restored
+  slice is uploaded (``upload``) and swapped back in (``install``).
 
 * **replication + health (ISSUE-7)**: with ``replicas=R`` each list lives
   on R shards -- replica r of list t on ``(splitmix64(t) + r) % n_shards``,
@@ -62,6 +67,11 @@ from repro.kernels.blockmax_pivot.kernel import QMIN_NONE
 from repro.kernels.vbyte_decode.kernel import BLOCK_BYTES, BLOCK_VALS
 
 INT32_MAX = np.iinfo(np.int32).max
+# stacked shards hold this many times an even share of the arena's blocks:
+# list-hash placement of Zipf-sized lists leaves the busiest of 4 shards
+# (2 replicas) under 1.24x the even share for 20,000 list orders of the
+# Gov2-shaped corpus, so the padded shapes do not move with the order
+SHARD_HEADROOM = 1.25
 
 
 def shard_of_list(lists: np.ndarray, n_shards: int) -> np.ndarray:
@@ -138,13 +148,15 @@ class ShardedArena:
     owner_r: np.ndarray | None = None   # [R, n_lists] replica owners
     local_r: np.ndarray | None = None   # [R, n_lists] local id per replica
     dead: np.ndarray | None = None      # [S] bool, honored by route()
+    evicted: np.ndarray | None = None   # [S] bool: device buffers deleted
     _shards: list | None = field(default=None, repr=False, compare=False)
-    _stacked_dev: dict | None = field(default=None, repr=False, compare=False)
     _rows_of: list | None = field(default=None, repr=False, compare=False)
     _pchunks: list | None = field(default=None, repr=False, compare=False)
-    _stacked_pivot_dev: dict | None = field(
-        default=None, repr=False, compare=False
-    )
+    # mesh path: per kind ("arena", "pivot") the [L, ...] arrays over the
+    # live shards' sub-mesh, each assembled from one buffer per device
+    _dev: dict = field(default_factory=dict, repr=False, compare=False)
+    _pad: dict | None = field(default=None, repr=False, compare=False)
+    _meshes: dict = field(default_factory=dict, repr=False, compare=False)
 
     @classmethod
     def build(cls, arena: DeviceArena, n_shards: int, mesh="auto", replicas: int = 1):
@@ -197,6 +209,7 @@ class ShardedArena:
             owner_r=owner_r,
             local_r=local_r,
             dead=np.zeros(n_shards, bool),
+            evicted=np.zeros(n_shards, bool),
         )
 
     # ------------------------------------------------------------------
@@ -280,85 +293,194 @@ class ShardedArena:
     def shard_nbytes(self) -> list[int]:
         return [sub.nbytes() for sub in self.shards]
 
+    def route_rows(self, rows) -> tuple[np.ndarray, np.ndarray]:
+        """(shard, shard-local row) of GLOBAL arena rows: each row goes to
+        the first live replica of its list (``route``) and maps onto that
+        shard's sub-arena through ``rows_of``.  Raises ``ShardsUnavailable``
+        where a row's list has no live replica."""
+        rows = np.asarray(rows, dtype=np.int64)
+        a = self.arena
+        lists = a.part_list[a.part_of_block[rows]]
+        owner, _, served = self.route(lists)
+        if not served.all():
+            raise ShardsUnavailable(np.unique(lists[~served]))
+        local = np.empty(len(rows), np.int64)
+        for s in np.unique(owner):
+            m = owner == s
+            local[m] = np.searchsorted(self.rows_of[s], rows[m])
+        return owner, local
+
     # ------------------------------------------------------------------
     # stacked [S, ...] placement for the shard_map dispatch
     # ------------------------------------------------------------------
-    def stacked(self) -> dict:
-        """Host-side [S, ...] stacking, padded to the largest shard.
+    def live_shards(self) -> tuple:
+        """Shards whose device buffers are resident, ascending."""
+        return tuple(int(s) for s in np.flatnonzero(~self.evicted))
+
+    def live_mesh(self):
+        """The mesh over the live shards' devices (the whole mesh while no
+        shard is evicted): the shard_map dispatchers run over it."""
+        live = self.live_shards()
+        if len(live) == self.n_shards:
+            return self.mesh
+        if live not in self._meshes:
+            import jax
+
+            devs = self.mesh.devices.reshape(-1)
+            self._meshes[live] = jax.sharding.Mesh(
+                np.asarray([devs[s] for s in live]), ("shard",)
+            )
+        return self._meshes[live]
+
+    def _pads(self) -> dict:
+        """The per-shard extents every stacked array is padded to.
+
+        They depend on the arena alone, not on which lists hash where, so
+        every list-id order of one corpus stacks to the same shapes -- the
+        same device footprint and the same compiled programs -- and a
+        restored shard stacks to the shapes the programs were built for.
+        Partitions, lists and pivot chunks pad to the whole arena's counts
+        (small arrays); blocks pad to ``SHARD_HEADROOM`` times an even
+        share of the arena's blocks, or to the busiest shard where its
+        lists outgrow that.
+        """
+        if self._pad is None:
+            a = self.arena
+            chunks = -(-np.diff(a.list_blk_offsets) // BLOCK_VALS)
+            even = -(-self.replicas * a.n_blocks // self.n_shards)
+            busiest = max(len(rows) for rows in self.rows_of)
+            self._pad = {
+                "nb": max(1, busiest,
+                          min(a.n_blocks, int(np.ceil(SHARD_HEADROOM * even)))),
+                "np": max(1, len(a.first_blk)),
+                "nl": max(1, len(a.list_blk_offsets) - 1),
+                "nc": max(1, int(chunks.sum())),
+            }
+        return self._pad
+
+    def _stack_arena(self, s: int, sub: DeviceArena) -> dict:
+        """Shard s's arena tiles as host arrays padded to ``_pads``.
 
         Padding rows are benign by construction: lens=1/data=0 decodes to
         zeros, ``block_last`` pads with int32 max (no list's block range
         covers a padding row), and ``list_blk_offsets`` pads by repeating
         its last value so any staged-padding cursor resolves past-the-end.
         Every narrowing is checked (``to_i32``).
-
-        NOT cached: the only consumer is ``stacked_dev`` (which caches the
-        DEVICE copies); keeping the padded host stacking alive would pin a
-        redundant arena-sized buffer for the engine's lifetime.
         """
-        if self.arena.block_codec is not None:
+        if sub.block_codec is not None:
             # the shard_map bodies decode one codec; the engines gate the
             # mesh path off for multi-codec arenas before reaching here
             raise ValueError("shard_map stacking is single-codec; "
                              "multi-codec arenas use the host shard loop")
-        S = self.n_shards
-        nb_m = max(1, max(sub.n_blocks for sub in self.shards))
-        np_m = max(1, max(len(sub.first_blk) for sub in self.shards))
-        nl_m = max(1, max(len(f) for f in self.lists_of))
+        pad = self._pads()
+        nb_m, np_m, nl_m = pad["nb"], pad["np"], pad["nl"]
+        nb, nl = sub.n_blocks, len(self.lists_of[s])
         st = {
-            "lens": np.ones((S, nb_m, BLOCK_VALS), np.int32),
-            "data": np.zeros((S, nb_m, BLOCK_BYTES), np.uint8),
-            "block_base": np.zeros((S, nb_m), np.int32),
-            "block_last": np.full((S, nb_m), INT32_MAX, np.int32),
-            "part_of_block": np.zeros((S, nb_m), np.int32),
-            "first_blk": np.zeros((S, np_m), np.int32),
-            "list_blk_offsets": np.zeros((S, nl_m + 1), np.int32),
+            "lens": np.ones((nb_m, BLOCK_VALS), np.int32),
+            "data": np.zeros((nb_m, BLOCK_BYTES), np.uint8),
+            "block_base": np.zeros(nb_m, np.int32),
+            "block_last": np.full(nb_m, INT32_MAX, np.int32),
+            "part_of_block": np.zeros(nb_m, np.int32),
+            "first_blk": np.zeros(np_m, np.int32),
+            "list_blk_offsets": np.full(nl_m + 1, nb, np.int32),
         }
-        ranked = self.arena.ranked is not None
-        if ranked:
-            st["freq_lens"] = np.ones((S, nb_m, BLOCK_VALS), np.int32)
-            st["freq_data"] = np.zeros((S, nb_m, BLOCK_BYTES), np.uint8)
-            st["norm_q"] = np.zeros((S, nb_m, BLOCK_VALS), np.uint8)
-            st["idf"] = np.zeros((S, nl_m), np.float32)
-            st["lob"] = np.zeros((S, nb_m), np.int32)
-        for s, sub in enumerate(self.shards):
-            nb, nl = sub.n_blocks, len(self.lists_of[s])
-            st["lens"][s, :nb] = sub.lens[:nb]
-            st["data"][s, :nb] = sub.data[:nb]
-            st["block_base"][s, :nb] = to_i32(sub.block_base, "block_base")
-            st["block_last"][s, :nb] = to_i32(sub.block_last(), "block_last")
-            st["part_of_block"][s, :nb] = to_i32(sub.part_of_block, "part_of_block")
-            st["first_blk"][s, : len(sub.first_blk)] = to_i32(sub.first_blk, "first_blk")
-            lbo = to_i32(sub.list_blk_offsets, "list_blk_offsets")
-            st["list_blk_offsets"][s, : nl + 1] = lbo
-            st["list_blk_offsets"][s, nl + 1 :] = np.int32(nb)
-            if ranked:
-                r = sub.ranked
-                st["freq_lens"][s, :nb] = r.freq_lens[:nb]
-                st["freq_data"][s, :nb] = r.freq_data[:nb]
-                st["norm_q"][s, :nb] = r.norm_q
-                st["idf"][s, :nl] = r.idf
-                st["lob"][s, :nb] = to_i32(sub.part_list[sub.part_of_block], "lob")
+        st["lens"][:nb] = sub.lens[:nb]
+        st["data"][:nb] = sub.data[:nb]
+        st["block_base"][:nb] = to_i32(sub.block_base, "block_base")
+        st["block_last"][:nb] = to_i32(sub.block_last(), "block_last")
+        st["part_of_block"][:nb] = to_i32(sub.part_of_block, "part_of_block")
+        st["first_blk"][: len(sub.first_blk)] = to_i32(sub.first_blk, "first_blk")
+        st["list_blk_offsets"][: nl + 1] = to_i32(
+            sub.list_blk_offsets, "list_blk_offsets"
+        )
+        r = sub.ranked
+        if r is not None:
+            st["freq_lens"] = np.ones((nb_m, BLOCK_VALS), np.int32)
+            st["freq_data"] = np.zeros((nb_m, BLOCK_BYTES), np.uint8)
+            st["norm_q"] = np.zeros((nb_m, BLOCK_VALS), np.uint8)
+            st["idf"] = np.zeros(nl_m, np.float32)
+            st["lob"] = np.zeros(nb_m, np.int32)
+            st["freq_lens"][:nb] = r.freq_lens[:nb]
+            st["freq_data"][:nb] = r.freq_data[:nb]
+            st["norm_q"][:nb] = r.norm_q
+            st["idf"][:nl] = r.idf
+            st["lob"][:nb] = to_i32(sub.part_list[sub.part_of_block], "lob")
         return st
 
-    def stacked_dev(self) -> dict:
-        """The stacked arrays placed shard-per-device with NamedSharding."""
-        if self._stacked_dev is not None:
-            return self._stacked_dev
-        if self.mesh is None:
-            raise ValueError("stacked_dev() needs a mesh")
+    def _stack_pivot(self, pc) -> dict:
+        """One shard's pivot bound tiles padded to ``_pads``; padding
+        chunks stage nblk 0, so nothing survives them."""
+        nc_m, nc = self._pads()["nc"], len(pc.nblk)
+        qb = np.zeros((nc_m, BLOCK_VALS), np.int32)
+        nblk = np.zeros(nc_m, np.int32)
+        qb[:nc] = pc.qb
+        nblk[:nc] = pc.nblk
+        return {"qb_chunks": qb, "chunk_nblk": nblk}
+
+    def _upload(self, kind: str, s: int, sub: DeviceArena | None) -> dict:
+        """Shard s's ``kind`` arrays as [1, ...] buffers on its device,
+        stacked from ``sub`` (the restored slice) or from its host slice."""
+        import jax
+
+        if kind == "arena":
+            host = self._stack_arena(
+                s, sub if sub is not None else self.shards[s]
+            )
+        else:
+            from repro.core.engine_core import build_pivot_chunks
+
+            host = self._stack_pivot(
+                build_pivot_chunks(sub) if sub is not None
+                else self.pivot_chunks[s]
+            )
+        dev = self.mesh.devices.reshape(-1)[s]
+        return {k: jax.device_put(v[None], dev) for k, v in host.items()}
+
+    def _assemble(self, parts: dict) -> dict:
+        """{shard: {name: [1, ...] buffer on its device}} -> {name: [L,
+        ...] array over the live mesh}, sharing the buffers (no copy)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
-        sharding = NamedSharding(self.mesh, PartitionSpec("shard"))
-        self._stacked_dev = {
-            k: jax.device_put(v, sharding) for k, v in self.stacked().items()
+        live = sorted(parts)
+        sharding = NamedSharding(self.live_mesh(), PartitionSpec("shard"))
+        return {
+            k: jax.make_array_from_single_device_arrays(
+                (len(live),) + v.shape[1:], sharding,
+                [parts[s][k] for s in live],
+            )
+            for k, v in parts[live[0]].items()
         }
-        # the host sub-arena slices existed only to feed the stacking: on
-        # the mesh path nothing reads them after the device copies exist,
-        # so release them (the property rebuilds on demand if asked)
-        self._shards = None
-        return self._stacked_dev
+
+    def _split(self, arrs: dict) -> dict:
+        """Inverse of ``_assemble``: {shard: {name: its buffer}}."""
+        devs = list(self.mesh.devices.reshape(-1))
+        parts: dict = {}
+        for k, a in arrs.items():
+            for sh in a.addressable_shards:
+                parts.setdefault(devs.index(sh.device), {})[k] = sh.data
+        return parts
+
+    def _resident(self, kind: str) -> dict:
+        if self.mesh is None:
+            raise ValueError(f"the stacked {kind} arrays need a mesh")
+        got = self._dev.get(kind)
+        if got is None:
+            got = self._dev[kind] = self._assemble(
+                {s: self._upload(kind, s, None) for s in self.live_shards()}
+            )
+        return got
+
+    def stacked_dev(self) -> dict:
+        """The stacked arena tiles, each shard's on its own device."""
+        fresh = "arena" not in self._dev
+        got = self._resident("arena")
+        if fresh:
+            # the host sub-arena slices existed only to feed the stacking:
+            # on the mesh path nothing reads them after the device copies
+            # exist, so release them (the property rebuilds on demand)
+            self._shards = None
+        return got
 
     def stacked_pivot_dev(self) -> dict:
         """The [S, ...] pivot bound tiles, staged LAZILY and separately.
@@ -367,31 +489,44 @@ class ShardedArena:
         engines reads ``qb_chunks`` / ``chunk_nblk``; staging them inside
         ``stacked_dev`` would charge every search/bm25 mesh engine the
         host re-tiling plus ~n_blocks x 512 B of device memory for
-        arrays it never touches.  Padding chunks stage nblk 0 -- nothing
-        survives them.
+        arrays it never touches.
         """
-        if self._stacked_pivot_dev is not None:
-            return self._stacked_pivot_dev
-        if self.mesh is None:
-            raise ValueError("stacked_pivot_dev() needs a mesh")
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec
+        return self._resident("pivot")
 
-        S = self.n_shards
-        pcs = self.pivot_chunks
-        nc_m = max(1, max(len(pc.nblk) for pc in pcs))
-        qb = np.zeros((S, nc_m, BLOCK_VALS), np.int32)
-        nblk = np.zeros((S, nc_m), np.int32)
-        for s, pc in enumerate(pcs):
-            nc = len(pc.nblk)
-            qb[s, :nc] = pc.qb
-            nblk[s, :nc] = pc.nblk
-        sharding = NamedSharding(self.mesh, PartitionSpec("shard"))
-        self._stacked_pivot_dev = {
-            "qb_chunks": jax.device_put(qb, sharding),
-            "chunk_nblk": jax.device_put(nblk, sharding),
-        }
-        return self._stacked_pivot_dev
+    # ------------------------------------------------------------------
+    # losing and restoring one shard's device buffers (mesh path)
+    # ------------------------------------------------------------------
+    def evict(self, s: int) -> None:
+        """Delete shard s's buffers on its device.  The dispatchers then
+        run over the sub-mesh of the live shards (``live_mesh``), so no
+        cursor can reach s; routing has already marked it ``dead``."""
+        if self.mesh is None or self.evicted[s]:
+            return
+        parts = {kind: self._split(arrs) for kind, arrs in self._dev.items()}
+        self._dev = {}
+        self.evicted[s] = True
+        for kind, by_shard in parts.items():
+            for buf in by_shard.pop(s, {}).values():
+                buf.delete()
+            if by_shard:
+                self._dev[kind] = self._assemble(by_shard)
+
+    def upload(self, s: int, sub: DeviceArena) -> dict:
+        """Stack the restored slice of shard s and put it on its device,
+        for ``install``; safe off the serving thread (it only reads)."""
+        return {kind: self._upload(kind, s, sub) for kind in list(self._dev)}
+
+    def install(self, s: int, sub: DeviceArena, parts: dict) -> None:
+        """Swap shard s's uploaded buffers back into the stacked arrays
+        (at a batch boundary); a kind first staged after the upload is
+        stacked from ``sub`` here."""
+        if self.mesh is None or not self.evicted[s]:
+            return
+        split = {kind: self._split(arrs) for kind, arrs in self._dev.items()}
+        self.evicted[s] = False
+        for kind, by_shard in split.items():
+            by_shard[s] = parts.get(kind) or self._upload(kind, s, sub)
+            self._dev[kind] = self._assemble(by_shard)
 
 
 def _slice_arena(
@@ -489,11 +624,12 @@ class _ShardMapDispatch:
 
     ``__call__(local_terms, probes, cuts)`` takes cursors PRE-SORTED by
     owning shard (``cuts`` delimiting each shard's run, as produced by the
-    engines' stable argsort over owners), stages them into [S, B] int32
-    buffers (B = pow2 bucket of the fullest shard; padding cursors probe
-    local list 0 at docID 0), runs ONE jitted shard_map dispatch, and
-    slices each shard's run back out.  The int32 probe clip happens on the
-    host, before staging -- same subtlety as the unsharded path.
+    engines' stable argsort over owners), stages them into [L, B] int32
+    buffers (L the live shards, B = pow2 bucket of the fullest shard;
+    padding cursors probe local list 0 at docID 0), runs ONE jitted
+    shard_map dispatch over the live shards' mesh, and slices each shard's
+    run back out.  The int32 probe clip happens on the host, before
+    staging -- same subtlety as the unsharded path.
     """
 
     def __init__(
@@ -521,8 +657,13 @@ class _ShardMapDispatch:
         # exceeds it run in rounds, so gathered tiles stay bounded and jit
         # traces are reused (same role as TopKEngine.MAX_BUCKET unsharded)
         self.max_bucket = max_bucket
-        self._fn = None
-        self._sharding = None
+        # one jitted shard_map per set of live shards: an evicted shard's
+        # device leaves the mesh until its restored buffers are installed;
+        # per live set, the staged (B, ...) shapes its program has served
+        # and those ``warm`` loaded it for
+        self._fns: dict = {}
+        self._ran: dict = {}
+        self._warmed: dict = {}
 
     # padding value of the staged probe buffer; subclasses whose "probes"
     # are not docIDs (the pivot dispatch stages qmin there) override both
@@ -533,48 +674,49 @@ class _ShardMapDispatch:
         # resolve past-the-end after the merge, not wrap negative)
         return np.clip(p, 0, self.stride - 1)
 
-    def _stage(self, local_terms, probes, cuts):
+    def _stage(self, local_terms, probes, cuts, live):
         from repro.core.engine_core import pow2_bucket
 
-        S = self.sharded.n_shards
         counts = np.diff(cuts)
+        gone = np.ones(len(counts), bool)
+        gone[list(live)] = False
+        if counts[gone].any():
+            raise RuntimeError("cursors routed to evicted shards "
+                               f"{np.flatnonzero(gone & (counts > 0)).tolist()}")
         B = pow2_bucket(int(counts.max()) if len(counts) else 1)
         probes = np.asarray(probes)
-        tp = np.zeros((S, B), np.int32)
+        tp = np.zeros((len(live), B), np.int32)
         # probes may carry trailing axes (the pivot dispatch stages a
         # [128]-lane qmin tile per cursor); dim 0 stays the cursor axis
-        pp = np.full((S, B) + probes.shape[1:], self.PAD_PROBE, np.int32)
-        for s in range(S):
+        pp = np.full((len(live), B) + probes.shape[1:], self.PAD_PROBE, np.int32)
+        for i, s in enumerate(live):
             sl = slice(int(cuts[s]), int(cuts[s + 1]))
-            tp[s, : counts[s]] = local_terms[sl]
-            pp[s, : counts[s]] = self._clip_probes(probes[sl])
+            tp[i, : counts[s]] = local_terms[sl]
+            pp[i, : counts[s]] = self._clip_probes(probes[sl])
         return tp, pp, counts
 
-    def _put(self, arr):
+    def _put(self, arr, mesh):
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec
 
-        if self._sharding is None:
-            from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(arr, NamedSharding(mesh, PartitionSpec("shard")))
 
-            self._sharding = NamedSharding(self.sharded.mesh, PartitionSpec("shard"))
-        return jax.device_put(arr, self._sharding)
-
-    def _merge(self, outs, cuts, counts):
+    def _merge(self, outs, cuts, counts, live):
         merged = []
         for o in outs:
             o = np.asarray(o)
             # per-cursor outputs may carry trailing axes (the pivot
             # dispatch returns a [B, 128] lane list per shard)
             m = np.empty((int(cuts[-1]),) + o.shape[2:], o.dtype)
-            for s in range(self.sharded.n_shards):
-                m[int(cuts[s]) : int(cuts[s + 1])] = o[s, : counts[s]]
+            for i, s in enumerate(live):
+                m[int(cuts[s]) : int(cuts[s + 1])] = o[i, : counts[s]]
             merged.append(m)
         return merged
 
     def _body(self, arrs: dict, terms, probes):
         raise NotImplementedError
 
-    def _build(self):
+    def _build(self, mesh):
         import jax
         from jax.sharding import PartitionSpec as P
 
@@ -586,7 +728,7 @@ class _ShardMapDispatch:
 
         smap = jax.shard_map(
             wrapped,
-            mesh=self.sharded.mesh,
+            mesh=mesh,
             in_specs=(P("shard"), P("shard"), P("shard")),
             out_specs=P("shard"),
             check_vma=False,
@@ -597,13 +739,45 @@ class _ShardMapDispatch:
         """The stacked device arrays this dispatcher's body reads."""
         return self.sharded.stacked_dev()
 
+    def _run(self, live, tp, pp, seen: dict):
+        """ONE dispatch of the live shards' program on staged buffers,
+        its staged shape noted in ``seen``."""
+        mesh = self.sharded.live_mesh()
+        fn = self._fns.get(live)
+        if fn is None:
+            fn = self._fns[live] = self._build(mesh)
+        seen.setdefault(live, set()).add(pp.shape[1:])
+        return fn(self._arrs(), self._put(tp, mesh), self._put(pp, mesh))
+
     def _dispatch(self, local_terms, probes, cuts):
-        tp, pp, counts = self._stage(local_terms, probes, cuts)
-        if self._fn is None:
-            self._fn = self._build()
-        dev = self._arrs()
-        outs = self._fn(dev, self._put(tp), self._put(pp))
-        return self._merge(outs, cuts, counts)
+        live = self.sharded.live_shards()
+        tp, pp, counts = self._stage(local_terms, probes, cuts, live)
+        return self._merge(self._run(live, tp, pp, self._ran), cuts, counts, live)
+
+    def warm(self) -> None:
+        """Load the live shards' program, on padding cursors, for every
+        power-of-two bucket from the smallest any live set has served to
+        twice the largest (a lost shard's cursors move to a replica, at
+        most doubling its run), so the waves served while a shard is out
+        load no program.  Shapes this live set has run are skipped."""
+        import jax
+
+        live = self.sharded.live_shards()
+        ran = set().union(*self._ran.values())
+        if not live or not ran:
+            return
+        top = 2 * max(sh[0] for sh in ran)
+        if self.max_bucket is not None:
+            top = min(top, self.max_bucket)
+        want, b = set(), min(sh[0] for sh in ran)
+        while b <= top:
+            want |= {(b,) + sh[1:] for sh in ran}
+            b *= 2
+        done = self._ran.get(live, set()) | self._warmed.get(live, set())
+        for shape in sorted(want - done):
+            tp = np.zeros((len(live), shape[0]), np.int32)
+            pp = np.full((len(live),) + shape, self.PAD_PROBE, np.int32)
+            jax.block_until_ready(self._run(live, tp, pp, self._warmed))
 
     def __call__(self, local_terms, probes, cuts):
         counts = np.diff(cuts)
@@ -731,6 +905,40 @@ class ShardMapBM25(_ShardMapDispatch):
     def __call__(self, local_terms, probes, cuts):
         (contrib,) = super().__call__(local_terms, probes, cuts)
         return contrib
+
+
+class ShardMapScoreRows(ShardMapBM25):
+    """All-lane BM25 scores of shard-LOCAL arena rows, every shard at once.
+
+    Cursors are rows of their shard's stacked freq tiles (global rows map
+    through ``ShardedArena.route_rows``); each shard's chip gathers and
+    scores its own rows with ``bm25_score_blocks``.  A shard's tiles are a
+    pure row gather of the global arena and its idf the global list's, so
+    the [n, 128] f32 scores (aligned with the sorted cursor order) are
+    bit-identical to scoring the global arena.
+    """
+
+    def _body(self, arrs, rows, _probes):
+        import jax.numpy as jnp
+
+        from repro.kernels.bm25_score.ops import score_rows_graph
+
+        return (score_rows_graph(
+            arrs["freq_lens"][rows],
+            arrs["freq_data"][rows],
+            arrs["norm_q"][rows].astype(jnp.int32),
+            arrs["idf"][arrs["lob"][rows]],
+            self.norm_table,
+            self.k1p1,
+            self.backend,
+            self.interpret,
+        ),)
+
+    def __call__(self, local_rows, cuts):
+        # rows are the only per-cursor input: the probe slot stages zeros
+        return super().__call__(
+            local_rows, np.zeros(len(local_rows), np.int64), cuts
+        )
 
 
 class ShardMapPivot(_ShardMapDispatch):

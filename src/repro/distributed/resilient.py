@@ -211,10 +211,13 @@ class ResilientEngine:
         for core in getattr(eng, "_shard_cores", []) or []:
             if core is not None:
                 core.injector = injector
-        for attr in ("_smap_fn", "_smap_pivot"):
-            fn = getattr(eng, attr, None)
-            if fn is not None:
-                fn.injector = injector
+        for fn in self._dispatchers():
+            fn.injector = injector
+
+    def _dispatchers(self) -> list:
+        """The engine's ``shard_map`` dispatchers built so far."""
+        return [fn for attr in ("_smap_fn", "_smap_pivot", "_smap_rows")
+                if (fn := getattr(self.engine, attr, None)) is not None]
 
     # ------------------------------------------------------------------
     # checkpoint / recovery
@@ -253,9 +256,17 @@ class ResilientEngine:
 
     def _evict(self, s: int) -> None:
         """Simulate the loss: drop the shard's sub-arena and per-shard
-        engine state, so recovery provably rebuilds from the checkpoint
-        (routing never targets a dead shard, so the holes are unread)."""
+        engine state -- on a mesh, its buffers on its device -- so recovery
+        provably rebuilds from the checkpoint (routing never targets a dead
+        shard, and the mesh dispatchers run over the live shards alone).
+        Where the shard stays out for later waves, the live shards'
+        programs load here, once per set of live shards."""
         sa, eng = self.sa, self.engine
+        with obs.span("resilient.evict", shard=s):
+            sa.evict(s)
+            if self.recover_async or self.manager is None:
+                for fn in self._dispatchers():
+                    fn.warm()
         if sa._shards is not None:
             sa._shards[s] = None
         for attr in ("_shard_fns", "_shard_pivot_fns"):
@@ -279,8 +290,12 @@ class ResilientEngine:
                 replicas=self.sa.replicas,
                 step=self._ckpt_step,
             )
+            parts = {}
+            if self.sa.mesh is not None:
+                with obs.span("resilient.upload", shard=s):
+                    parts = self.sa.upload(s, sub)
             with self._lock:
-                self._ready[s] = sub
+                self._ready[s] = (sub, parts)
 
         if self.recover_async:
             t = threading.Thread(target=work, daemon=True)
@@ -291,12 +306,16 @@ class ResilientEngine:
 
     def _admit_recovered(self) -> None:
         """Install restored sub-arenas at a batch boundary: re-slot the
-        slice, rebuild the per-shard core, clear the dead mask, revive."""
+        slice (on a mesh, swap its uploaded buffers into the stacked
+        arrays, so the dispatchers run over every shard again), rebuild the
+        per-shard core, clear the dead mask, revive."""
         with self._lock:
             ready = list(self._ready.items())
             self._ready.clear()
-        for s, sub in ready:
+        for s, (sub, parts) in ready:
             sa, eng = self.sa, self.engine
+            with obs.span("resilient.admit", shard=s):
+                sa.install(s, sub, parts)
             if sa._shards is not None:
                 sa._shards[s] = sub
             cores = getattr(eng, "_shard_cores", None)
@@ -393,8 +412,11 @@ class ResilientEngine:
                     continue
                 self._mark_dead(s)
                 # a synchronous recovery has already restored by now:
-                # re-admit immediately so THIS batch is served whole
-                self._admit_recovered()
+                # re-admit immediately so THIS batch is served whole (an
+                # asynchronous one re-admits at a later batch boundary,
+                # however soon its restore ends)
+                if not self.recover_async:
+                    self._admit_recovered()
                 continue
             self._note_success()
             info = ServeInfo(

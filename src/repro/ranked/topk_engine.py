@@ -229,6 +229,7 @@ class TopKEngine:
         self._pivot_fn = None
         self._shard_pivot_fns: list = []
         self._smap_pivot = None
+        self._smap_rows = None
         # fully-resident round state (DESIGN.md §13): the fused pivot+score
         # dispatch, the resident row scorer, and the device theta round
         self._pivot_score_fn = None
@@ -247,6 +248,8 @@ class TopKEngine:
             )
             self._shard_fns = [None] * self.sharded.n_shards
             self._shard_pivot_fns = [None] * self.sharded.n_shards
+            for s in range(self.sharded.n_shards):
+                self.stats[f"scored_rows_shard{s}"] = 0  # on the mesh
 
     def _check_shard(self, s: int) -> None:
         """Host-loop shard-dispatch fault boundary (the shard_map
@@ -527,9 +530,40 @@ class TopKEngine:
         rp[: len(mrows)] = mrows
         return self._rowscore_fn(jnp.asarray(rp))
 
+    def _score_rows_sharded(self, mrows: np.ndarray) -> np.ndarray:
+        """Score global arena rows on their shards' chips: each row goes to
+        a live owner of its list (primary first, then a replica), maps to
+        that shard's local row, and every shard scores its rows in ONE
+        ``shard_map`` dispatch over the stacked freq tiles.  Scores come
+        back under their global rows, bit-identical to the global arena's
+        (a shard's tiles are a pure row gather of it)."""
+        sa = self.sharded
+        if self._smap_rows is None:
+            from repro.core.shard import ShardMapScoreRows
+
+            self._smap_rows = ShardMapScoreRows(
+                sa, backend=self.backend, interpret=self.interpret,
+                k1p1=float(self.k1p1), max_bucket=self.MAX_BUCKET,
+                injector=self.fault_injector,
+            )
+        with obs.span("shard_score", path="ranked"):
+            owner, local = sa.route_rows(mrows)
+            order = np.argsort(owner, kind="stable")
+            cuts = np.searchsorted(owner[order], np.arange(sa.n_shards + 1))
+            out = np.empty((len(mrows), BLOCK_VALS), np.float32)
+            out[order] = self._smap_rows(local[order], cuts)
+        for s, n in enumerate(np.diff(cuts)):
+            if n:
+                self.stats[f"scored_rows_shard{s}"] += int(n)
+        return out
+
     def _score_miss_rows(self, mrows: np.ndarray) -> np.ndarray:
         """Score UNIQUE SORTED cache-miss rows: resident dispatch on an
-        unsharded device backend, host-gather kernel wrapper otherwise."""
+        unsharded device backend, on the shards' chips on a mesh,
+        host-gather kernel wrapper otherwise."""
+        if (self.sharded is not None and self.sharded.mesh is not None
+                and self.core.use_device):
+            return self._score_rows_sharded(mrows)
         if self.sharded is None and self.core.use_device:
             n = len(mrows)
             out = np.empty((n, BLOCK_VALS), np.float32)

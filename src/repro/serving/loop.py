@@ -33,6 +33,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from repro import obs
+from repro.distributed.resilient import ServeInfo
 from repro.serving.batcher import BatchFormer
 
 
@@ -51,6 +52,8 @@ class ServeResult:
     expired: bool
     wait_s: float     # admission -> wave formation
     service_s: float  # wave formation -> result (0.0 when expired)
+    # served from live lists only: the wave's ``ServeInfo.degraded``
+    degraded: bool = False
 
     @property
     def latency_s(self) -> float:
@@ -61,7 +64,11 @@ _EMPTY = (np.zeros(0, np.int64), np.zeros(0, np.float64))
 
 
 class AsyncTopKServer:
-    """Continuous-batching front for a ``TopKEngine``.
+    """Continuous-batching front for a ``TopKEngine``, or for a
+    ``ResilientEngine`` over one: its ``(results, ServeInfo)`` pair is taken
+    apart, waves with a failed shard count as ``failover_waves`` and
+    degraded ones as ``degraded_waves``, and a degraded wave's results are
+    marked ``degraded``.
 
     Parameters mirror the ``launch.serve --loop`` flags (docs/serving.md):
     ``max_batch`` wave cap, ``max_queue`` backpressure bound,
@@ -94,6 +101,8 @@ class AsyncTopKServer:
             "shed": 0,
             "backpressure_waits": 0,
             "padded_queries": 0,
+            "failover_waves": 0,
+            "degraded_waves": 0,
         }
         self._wake = asyncio.Event()
         self._space = asyncio.Event()
@@ -169,6 +178,12 @@ class AsyncTopKServer:
             with obs.timer("serve_wave_ms", engine="topk"):
                 outs = self.engine.topk_batch(queries, self.k)
             t_done = self.clock()
+            degraded = False
+            if isinstance(outs, tuple) and isinstance(outs[1], ServeInfo):
+                outs, info = outs
+                degraded = bool(info.degraded)
+                self.stats["failover_waves"] += bool(info.failed_shards)
+                self.stats["degraded_waves"] += degraded
             for req, (docs, scores) in zip(batch, outs):
                 self.stats["served"] += 1
                 if req.deadline < t_done:
@@ -177,7 +192,7 @@ class AsyncTopKServer:
                 self._resolve(req, ServeResult(
                     docs, scores, expired=False,
                     wait_s=t_form - req.enqueued,
-                    service_s=t_done - t_form,
+                    service_s=t_done - t_form, degraded=degraded,
                 ))
             obs.set_gauge("serve_queue_depth", self.former.depth)
             return True

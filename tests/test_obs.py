@@ -449,3 +449,33 @@ def test_topk_counts_membership_pairs(ranked_index, monkeypatch, resident):
     assert eng.stats["membership_pairs"] == sum(passes) > 0
     counter = obs.counter("ranked_membership_pairs", engine="topk")
     assert counter.value == sum(passes)
+
+
+def test_shard_score_and_failover_spans_and_per_shard_rows(tmp_path,
+                                                           ranked_index):
+    """On a mesh (one device, one shard here) the kernel-resident ranked
+    engine scores its rows inside ``shard_score`` and counts them per
+    shard; a kill and its restore open ``resilient.evict``,
+    ``resilient.upload`` and ``resilient.admit``."""
+    from repro.checkpoint import CheckpointManager
+    from repro.distributed.resilient import ResilientEngine, ShardFaultInjector
+    from repro.ranked.topk_engine import TopKEngine
+
+    idx, queries = ranked_index
+    eng = TopKEngine(idx, backend="ref", seed_blocks=2, resident="kernel",
+                     shards=1)
+    assert eng.sharded.mesh is not None
+    res = ResilientEngine(
+        eng, injector=ShardFaultInjector(at_batches=(1,), shards=(0,)),
+        manager=CheckpointManager(tmp_path, async_save=False), backoff_s=1e-4,
+    )
+    res.checkpoint()
+    for i in range(0, len(queries), 4):
+        res.topk_batch(queries[i : i + 4], 10)
+    spans = {e["name"] for e in obs.events() if e["kind"] == "span"}
+    assert {"shard_score", "resilient.evict", "resilient.upload",
+            "resilient.admit"} <= spans
+    rows = eng.stats["scored_rows_shard0"]
+    assert rows == eng.stats["scored_rows"] > 0
+    assert obs.counter("ranked_scored_rows_shard0", engine="topk").value == rows
+    assert res.stats["recoveries"] == 1

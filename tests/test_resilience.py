@@ -187,6 +187,63 @@ def test_restore_shard_skips_corrupt_step(tmp_path, index):
         restore_shard(m, 0, 2, step=2)  # explicit step: no fallback
 
 
+def test_restore_decodes_keys_past_int32(tmp_path, corpus):
+    """Packed leaves decode on the host in int64: a value past 2^31 round-
+    trips, and so does a shard whose block keys (list x stride + docID)
+    pass 2^31 -- the Gov2-shaped arena's case."""
+    from repro.checkpoint.manager import (
+        pack_sorted_int_array,
+        unpack_sorted_int_array,
+    )
+
+    arr = np.array([1, 2**31 + 7, 2**40], np.int64)
+    back = unpack_sorted_int_array(pack_sorted_int_array(arr))
+    assert back.dtype == np.int64 and np.array_equal(back, arr)
+    spread = [np.asarray(c, np.int64) * 40_000 for c in corpus]
+    idx = build_partitioned_index(spread, "optimal")
+    assert int(idx.arena.block_keys.max()) > 2**31
+    m = CheckpointManager(tmp_path, async_save=False)
+    save_arena(m, idx.arena)
+    sa = ShardedArena.build(idx.arena, 2, mesh=None, replicas=2)
+    for s in range(2):
+        sub, _ = restore_shard(m, s, 2, replicas=2)
+        assert int(sub.block_keys.max()) > 2**31
+        _assert_same_arena(sa.shards[s], sub)
+
+
+
+def test_async_restore_readmits_at_a_later_batch(tmp_path, index, queries,
+                                                monkeypatch):
+    """An asynchronous restore re-admits its shard at a later batch
+    boundary however soon it ends: here it has ended before the batch that
+    lost the shard retries, and that batch is still served without it."""
+    import threading
+
+    start = threading.Thread.start
+
+    def start_and_finish(self):
+        start(self)
+        self.join()
+
+    monkeypatch.setattr(threading.Thread, "start", start_and_finish)
+    want = QueryEngine(index, backend="numpy").intersect_batch(queries[:12])
+    res = ResilientEngine(
+        QueryEngine(index, backend="numpy", shards=3, replicas=2,
+                    shard_mesh=None),
+        injector=ShardFaultInjector(at_batches=(1,), shards=(0,)),
+        manager=CheckpointManager(tmp_path, async_save=False),
+        backoff_s=1e-4, recover_async=True,
+    )
+    res.checkpoint()
+    got = []
+    for i, dead_after in enumerate((False, True, False)):
+        out, info = res.intersect_batch(queries[4 * i: 4 * i + 4])
+        got += out
+        assert bool(res.sa.dead[0]) == dead_after
+        assert bool(info.failed_shards) == (i == 1) and not info.degraded
+    assert all(np.array_equal(g, w) for g, w in zip(got, want))
+    assert res.stats["recoveries"] == 1 and res.health == [HEALTHY] * 3
+
 # ----------------------------------------------------------------------
 # replica routing
 # ----------------------------------------------------------------------
@@ -528,6 +585,94 @@ def test_degraded_serving_counted_lifecycle_stops_at_dead(index, queries,
     snap = obs.snapshot(events=False)
     assert snap["counters"]["resilient_degraded_answers"] >= 1
     assert "resilient_recovery_ms{shard=\"0\"}" not in snap["histograms"]
+
+
+def test_topk_mesh_failover_scores_on_shards_subprocess():
+    """Kernel-resident top-10 on a 4-device mesh (4 shards x 2 replicas,
+    svb): rows score on their shards' devices, and the answers equal
+    ``exhaustive_topk`` exactly before a kill, while shard 1 is dead and
+    evicted from its device (the dispatches run over the other three, on
+    programs loaded at the eviction), and after its restored buffers are
+    re-admitted on device 1."""
+    script = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys, json, tempfile
+        sys.path.insert(0, "src")
+        import numpy as np
+        import jax
+        from repro.checkpoint import CheckpointManager
+        from repro.core.index import build_partitioned_index
+        from repro.data.postings import make_corpus, make_freqs
+        from repro.distributed.resilient import ResilientEngine, ShardFaultInjector
+        from repro.ranked.bm25 import exhaustive_topk
+        from repro.ranked.topk_engine import TopKEngine
+
+        rng = np.random.default_rng(3)
+        corpus = make_corpus(rng, n_lists=12, min_len=200, max_len=3000,
+                             mean_dense_gap=2.13, frac_dense=0.8)
+        idx = build_partitioned_index(corpus, "optimal", freqs=make_freqs(rng, corpus),
+                                      codecs="svb")
+        queries = [[int(t) for t in rng.choice(12, size=int(rng.integers(1, 4)),
+                                               replace=False)] for _ in range(24)]
+        want = exhaustive_topk(idx, queries, 10)
+
+        def same(got, qs):
+            w = [want[queries.index(q)] for q in qs]
+            return all(np.array_equal(gd, wd) and np.array_equal(gs, ws)
+                       for (gd, gs), (wd, ws) in zip(got, w))
+
+        eng = TopKEngine(idx, backend="ref", shards=4, replicas=2, resident="kernel",
+                         codec_policy="svb", seed_blocks=2)
+        sa = eng.sharded
+        dev1 = sa.mesh.devices.reshape(-1)[1]
+        ok = {"mesh": sa.mesh is not None and sa.mesh.devices.size == 4}
+        with tempfile.TemporaryDirectory() as d:
+            res = ResilientEngine(eng,
+                                  injector=ShardFaultInjector(at_batches=(1,), shards=(1,)),
+                                  manager=CheckpointManager(d, async_save=False),
+                                  recover_async=True, backoff_s=1e-4)
+            res.checkpoint()
+            got, info = res.topk_batch(queries[:8], 10)
+            ok["before"] = same(got, queries[:8]) and not info.failed_shards
+            old = [sh.data for a in sa._dev["arena"].values()
+                   for sh in a.addressable_shards if sh.device == dev1]
+            ok["old_on_dev1"] = len(old) > 0 and not any(b.is_deleted() for b in old)
+            eng._scache_rows = np.zeros(0, np.int64)   # force re-scoring while dead
+            eng._scache = np.zeros((0, 128), np.float32)
+            got, info = res.topk_batch(queries[:8], 10)
+            ok["during"] = (same(got, queries[:8]) and set(info.failed_shards) == {1}
+                            and not info.degraded and sa.live_shards() == (0, 2, 3))
+            # the three live shards' programs loaded at the eviction, for every
+            # bucket the same batch can stage on them
+            ok["warmed"] = all(fn._ran.get((0, 2, 3), set()) <= fn._warmed.get((0, 2, 3), set())
+                               for fn in res._dispatchers())
+            ok["deleted"] = all(b.is_deleted() for b in old)
+            ok["no_dev1"] = not any(sh.device == dev1 for a in sa._dev["arena"].values()
+                                    for sh in a.addressable_shards)
+            res.wait_recovered(60)
+            eng._scache_rows = np.zeros(0, np.int64)
+            eng._scache = np.zeros((0, 128), np.float32)
+            got, info = res.topk_batch(queries[16:], 10)
+            ok["after"] = same(got, queries[16:]) and sa.live_shards() == (0, 1, 2, 3)
+            new = [sh.data for a in sa._dev["arena"].values()
+                   for sh in a.addressable_shards if sh.device == dev1]
+            ok["new_on_dev1"] = len(new) == len(old) and not any(b.is_deleted() for b in new)
+            ok["health"] = res.health == ["HEALTHY"] * 4
+        per = [eng.stats[f"scored_rows_shard{s}"] for s in range(4)]
+        ok["shards_scored"] = sum(n > 0 for n in per)
+        ok["per"] = per
+        print(json.dumps(ok))
+    """)
+    out = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True,
+        cwd=pathlib.Path(__file__).parent.parent, timeout=90,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res.pop("shards_scored") > 1, res
+    per = res.pop("per")
+    assert all(res.values()), (res, per)
 
 
 @pytest.mark.slow

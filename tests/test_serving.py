@@ -264,3 +264,51 @@ def test_drain_ignores_linger(engine):
 
     res, server = asyncio.run(drive())
     assert not res.expired and server.former.depth == 0
+
+
+@pytest.mark.parametrize("replicas", [2, 1])
+def test_server_serves_resilient_engine_through_a_kill(engine, replicas):
+    """A ``ResilientEngine`` behind the server: shard 1 dies at the second
+    wave.  With a replica every answer equals ``exhaustive_topk`` and the
+    wave that lost the shard counts as a failover; with none, the waves
+    after the kill that ask for a lost list are degraded, marked so, and
+    answer the queries restricted to the live lists exactly."""
+    from repro.distributed.resilient import ResilientEngine, ShardFaultInjector
+    from repro.ranked.bm25 import exhaustive_topk
+
+    sharded = TopKEngine(engine.index, backend="numpy", resident="kernel",
+                         shards=2, replicas=replicas)
+    res = ResilientEngine(
+        sharded, injector=ShardFaultInjector(at_batches=(1,), shards=(1,)),
+        backoff_s=1e-4,
+    )
+    queries = _queries(engine, np.random.default_rng(9), 23)
+
+    async def drive():
+        async with AsyncTopKServer(
+            res, k=10, max_batch=4, max_delay_s=1e-3
+        ) as server:
+            out = []
+            for i in range(0, len(queries), 4):  # one wave at a time
+                out += await asyncio.gather(
+                    *(server.submit(q) for q in queries[i:i + 4]))
+            return out, server
+
+    results, server = asyncio.run(drive())
+    lost = set(res.sa.unserved_lists().tolist())
+    live = [[t for t in q if t not in lost] for q in queries]
+    for r, (wd, ws) in zip(results, exhaustive_topk(engine.index, live, 10)):
+        assert not r.expired
+        assert np.array_equal(r.docs, wd) and np.array_equal(r.scores, ws)
+    assert server.stats["failover_waves"] == 1
+    degraded = [r.degraded for r in results]
+    if replicas == 2:
+        assert not lost and not any(degraded)
+        assert server.stats["degraded_waves"] == 0
+    else:
+        # a wave is degraded from the kill on where it asks for a lost list
+        hit = [i >= 4 and any(lost.intersection(q) for q in
+                              queries[i - i % 4: i - i % 4 + 4])
+               for i in range(len(queries))]
+        assert lost and any(hit) and degraded == hit
+        assert server.stats["degraded_waves"] == sum(hit[::4])

@@ -29,11 +29,10 @@ V5E_HBM_BYTES = 16 * 2**30
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
@@ -47,10 +46,17 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
     mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _kernel_cases():
@@ -171,4 +177,57 @@ def test_graph_half_compiles_for_v5e(one_chip, name):
     compiled = _compile(fn, specs, one_chip)
     # locate is plain XLA; every other half dispatches a Pallas kernel
     assert ("tpu_custom_call" in compiled.as_text()) == (name != "locate_graph")
+    _check_fits(compiled)
+
+
+@pytest.mark.parametrize("live", [(0, 1, 2, 3), (0, 2, 3)])
+@pytest.mark.parametrize("dispatch", ["ShardMapScoreRows", "ShardMapPivot"])
+def test_shard_map_dispatch_compiles_for_v5e_2x2(topo, dispatch, live):
+    """The sharded top-k's two ``shard_map`` programs over four chips, and
+    over the three left while one shard is evicted, at a Gov2-sized
+    shard's stacked extents."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import shard
+    from repro.core.index import build_partitioned_index
+    from repro.data.postings import make_corpus, make_freqs
+    from repro.kernels.vbyte_decode.kernel import BLOCK_BYTES, BLOCK_VALS
+
+    rng = np.random.default_rng(0)
+    lists = make_corpus(rng, n_lists=4, min_len=200, max_len=600)
+    idx = build_partitioned_index(lists, "optimal",
+                                  freqs=make_freqs(rng, lists), codecs="svb")
+    sa = shard.ShardedArena.build(
+        idx.arena_for("svb"), 1,
+        mesh=Mesh(np.asarray(jax.devices()[:1]), ("shard",)),
+    )
+    mesh = Mesh(np.asarray([topo.devices[i] for i in live]), ("shard",))
+    sharding = NamedSharding(mesh, PartitionSpec("shard"))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct((len(live),) + shape, dtype,
+                                    sharding=sharding)
+
+    nb, nl, nc = 1 << 18, 1 << 11, 1 << 12  # a Gov2-sized shard, padded
+    if dispatch == "ShardMapScoreRows":
+        fn = shard.ShardMapScoreRows(sa, "pallas", False, k1p1=2.2)
+        arrs = {
+            "freq_lens": spec((nb, BLOCK_VALS), jnp.int32),
+            "freq_data": spec((nb, BLOCK_BYTES), jnp.uint8),
+            "norm_q": spec((nb, BLOCK_VALS), jnp.uint8),
+            "idf": spec((nl,), jnp.float32),
+            "lob": spec((nb,), jnp.int32),
+        }
+        probes = spec((ROWS,), jnp.int32)
+    else:
+        fn = shard.ShardMapPivot(sa, "pallas", False)
+        arrs = {"qb_chunks": spec((nc, BLOCK_VALS), jnp.int32),
+                "chunk_nblk": spec((nc,), jnp.int32)}
+        probes = spec((ROWS, BLOCK_VALS), jnp.int32)
+    compiled = fn._build(mesh).lower(
+        arrs, spec((ROWS,), jnp.int32), probes
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     _check_fits(compiled)
